@@ -192,7 +192,7 @@ def main(argv=None):
     from paddle_tpu.observability.memory import backend_bandwidth_gbs
 
     backend = jax.default_backend()
-    bw_gbs = backend_bandwidth_gbs(backend)
+    bw_gbs = backend_bandwidth_gbs(jax.devices()[0].device_kind)
     results = []
     if "sim_curve" in only:
         results.extend(_bench_sim_curve(backend))

@@ -281,9 +281,9 @@ def measure_config(name, steps=4, windows=2):
         best = dt if best is None else min(best, dt)
     step_s = best / steps
 
-    backend = jax.default_backend()
-    comms_s = comms.modeled_comms_seconds(report, backend)
-    comms.publish_dispatch("multichip", name, report, step_s, backend)
+    kind = jax.devices()[0].device_kind
+    comms_s = comms.modeled_comms_seconds(report, kind)
+    comms.publish_dispatch("multichip", name, report, step_s, kind)
     by_op = report.calls_by_op()
     row = {
         "metric": f"multichip comms {name} step (cpu8)",

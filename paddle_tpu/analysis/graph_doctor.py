@@ -137,28 +137,22 @@ def diagnose_program(fetch_list, program=None, file="<static.Program>"):
 # jaxpr doctor
 
 
-def _eqn_line(eqn, default=0):
-    try:  # best effort: jax internal source-info API
-        from jax._src import source_info_util
+def _eqn_frame(eqn):
+    """The user's frame an eqn was traced from, or None (jax keeps the
+    frame filter private; there is no public spelling)."""
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return frame.start_line
-    except Exception:
-        pass
-    return default
+    return source_info_util.user_frame(eqn.source_info.traceback)
+
+
+def _eqn_line(eqn, default=0):
+    frame = _eqn_frame(eqn)
+    return default if frame is None else frame.start_line
 
 
 def _eqn_file(eqn, default="<jaxpr>"):
-    try:
-        from jax._src import source_info_util
-
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is not None:
-            return frame.file_name
-    except Exception:
-        pass
-    return default
+    frame = _eqn_frame(eqn)
+    return default if frame is None else frame.file_name
 
 
 def _axis_names(params):
@@ -175,16 +169,16 @@ def _axis_names(params):
 
 
 def _sub_jaxprs(params):
-    import jax
+    from jax.extend.core import ClosedJaxpr
 
     for v in params.values():
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, ClosedJaxpr):
             yield v.jaxpr
         elif hasattr(v, "eqns") and hasattr(v, "outvars"):
             yield v
         elif isinstance(v, (tuple, list)):
             for w in v:
-                if isinstance(w, jax.core.ClosedJaxpr):
+                if isinstance(w, ClosedJaxpr):
                     yield w.jaxpr
                 elif hasattr(w, "eqns") and hasattr(w, "outvars"):
                     yield w
@@ -194,7 +188,7 @@ def diagnose_jaxpr(closed_jaxpr, mesh_axes=None, file="<jaxpr>"):
     """Diagnose a (Closed)Jaxpr. ``mesh_axes``: the axis names the program
     will run under (e.g. fleet topology dims); collectives over other
     names report PTA505. With mesh_axes=None the axis check is skipped."""
-    import jax
+    from jax.extend.core import Literal
 
     jaxpr = getattr(closed_jaxpr, "jaxpr", closed_jaxpr)
     mesh_axes = set(mesh_axes) if mesh_axes is not None else None
@@ -202,7 +196,7 @@ def diagnose_jaxpr(closed_jaxpr, mesh_axes=None, file="<jaxpr>"):
 
     # ---- liveness, walked backward; effectful eqns stay live ----
     live_vars = {v for v in jaxpr.outvars
-                 if not isinstance(v, jax.core.Literal)}
+                 if not isinstance(v, Literal)}
     live_eqns = [False] * len(jaxpr.eqns)
     for i in range(len(jaxpr.eqns) - 1, -1, -1):
         eqn = jaxpr.eqns[i]
@@ -211,7 +205,7 @@ def diagnose_jaxpr(closed_jaxpr, mesh_axes=None, file="<jaxpr>"):
         if effectful or any(v in live_vars for v in eqn.outvars):
             live_eqns[i] = True
             for v in eqn.invars:
-                if not isinstance(v, jax.core.Literal):
+                if not isinstance(v, Literal):
                     live_vars.add(v)
 
     for i, eqn in enumerate(jaxpr.eqns):

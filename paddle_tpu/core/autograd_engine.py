@@ -130,7 +130,7 @@ def _make_replay_bw(node):
     the forward (node.replay) at those inputs and pull the cotangents
     back. Routed through op_call.apply, this records a tape node whose own
     vjp gives second-order gradients."""
-    from .op_call import _match_vma, _typeof
+    from .op_call import _match_vma
 
     replay = node.replay
     k = len(node.inputs)
@@ -141,7 +141,7 @@ def _make_replay_bw(node):
         out_data, vjp = jax.vjp(replay, *prim)
         flat = (list(out_data) if isinstance(out_data, (tuple, list))
                 else [out_data])
-        cts = [_match_vma(c, _typeof(o)) for c, o in zip(cots, flat)]
+        cts = [_match_vma(c, jax.typeof(o)) for c, o in zip(cots, flat)]
         res = vjp(cts[0]) if len(flat) == 1 else vjp(tuple(cts))
         # apply()'s convention: single outputs are bare, not 1-tuples
         # (_VjpAdapter keys its cotangent structure on that)

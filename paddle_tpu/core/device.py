@@ -6,6 +6,8 @@ so this is a thin veneer over jax.devices() that preserves the Paddle API.
 
 from __future__ import annotations
 
+import warnings
+
 import jax
 
 
@@ -25,13 +27,25 @@ _CURRENT = [None]
 
 
 def set_device(device: str):
-    """Accepts 'tpu', 'cpu', 'tpu:0' etc. Returns the Place."""
+    """Accepts 'tpu', 'cpu', 'tpu:0' etc. Returns the Place.  A platform
+    or an index that is not there raises: asking for a TPU must never
+    quietly hand back a CPU."""
     name = device.split(":")[0]
     idx = int(device.split(":")[1]) if ":" in device else 0
     if name in ("gpu", "cuda", "xpu"):
-        name = _default_platform()  # gracefully map reference device names
-    devs = [d for d in jax.devices() if d.platform == name] or jax.devices()
-    _CURRENT[0] = Place(devs[min(idx, len(devs) - 1)])
+        # the reference's device names mean "the accelerator": map them
+        # to the default platform, and say so
+        mapped = _default_platform()
+        warnings.warn(f"set_device({device!r}): no such platform here; "
+                      f"using the default platform {mapped!r}")
+        name = mapped
+    devs = [d for d in jax.devices() if d.platform == name]
+    if idx >= len(devs):
+        have = sorted({d.platform for d in jax.devices()})
+        raise ValueError(
+            f"set_device({device!r}): {len(devs)} {name!r} device(s) "
+            f"present (platforms here: {have})")
+    _CURRENT[0] = Place(devs[idx])
     return _CURRENT[0]
 
 

@@ -18,12 +18,6 @@ from . import tape as _tape
 from .tensor import Tensor
 
 
-try:
-    _typeof = jax.typeof
-except AttributeError:  # jax < 0.6: typeof not exported; avals via core
-    from jax.core import get_aval as _typeof
-
-
 def _is_float(dtype) -> bool:
     return jnp.issubdtype(dtype, jnp.floating) or jnp.issubdtype(dtype, jnp.complexfloating)
 
@@ -125,7 +119,7 @@ def apply(fn, *args, _op_name: str = "", **kwargs):
         _tape.global_tape().record(
             diff_tensors,
             out_tensors,
-            _VjpAdapter(vjp_fn, [_typeof(o) for o in outs]),
+            _VjpAdapter(vjp_fn, [jax.typeof(o) for o in outs]),
             name=_op_name or getattr(fn, "__name__", "op"),
             replay=primal,
             in_data=diff_data,
@@ -141,7 +135,7 @@ def _match_vma(ct, expected_aval):
     vma = getattr(expected_aval, "vma", None)
     if not vma:
         return ct
-    have = getattr(_typeof(ct), "vma", frozenset())
+    have = getattr(jax.typeof(ct), "vma", frozenset())
     missing = tuple(vma - have)
     if missing:
         ct = jax.lax.pcast(ct, missing, to="varying")

@@ -19,14 +19,28 @@ import jax.numpy as jnp
 
 
 class _KeyStream:
-    """fold_in-counter key stream: cheap, traceable, replayable."""
+    """fold_in-counter key stream: cheap, traceable, replayable.
+
+    A stream made from an int seed builds its base key on first use, not
+    at construction: the module-level default stream is made by
+    ``import paddle_tpu``, and building a key initializes the backend —
+    a parent process that only imports the package (the launcher, a
+    DataLoader worker, a bench driver) must not take the chip."""
 
     def __init__(self, seed_or_key):
         if isinstance(seed_or_key, int):
-            self.base = jax.random.PRNGKey(seed_or_key)
+            self._seed, self._base = seed_or_key, None
         else:
-            self.base = seed_or_key
+            self._seed, self._base = None, seed_or_key
         self.counter = 0
+
+    @property
+    def base(self):
+        if self._base is None:
+            # concrete even when first touched inside a trace
+            with jax.ensure_compile_time_eval():
+                self._base = jax.random.PRNGKey(self._seed)
+        return self._base
 
     def next_key(self):
         k = jax.random.fold_in(self.base, self.counter)
@@ -37,7 +51,7 @@ class _KeyStream:
         return (self.base, self.counter)
 
     def set_state(self, st):
-        self.base, self.counter = st
+        self._base, self.counter = st
 
 
 class _RandomState(threading.local):

@@ -159,14 +159,18 @@ def launch(argv=None):
     while True:
         if attempt == 0 and not args.log_dir and not args.master:
             # common case: run in-process (no fork) — jax owns the devices.
-            # Multi-node runs (--master) MUST fork instead: this launcher
-            # process already imported paddle_tpu (touching the XLA
-            # backend), and the coordination-service rendezvous has to
-            # happen before the backend initializes in the training process.
+            # Multi-node runs (--master) MUST fork instead: paddle_tpu
+            # joins the coordination service while it is imported, from
+            # the env contract _export_env() has only just written, and
+            # this launcher process imported it before that.
             sys.argv = [args.script] + list(args.script_args)
             runpy.run_path(args.script, run_name="__main__")
             return 0
-        # watcher mode: subprocess so a crash can be observed and restarted
+        # watcher mode: subprocess so a crash can be observed and
+        # restarted.  A chip belongs to one process at a time and the
+        # worker needs it: this parent has imported paddle_tpu, which
+        # initializes no backend (tests/test_chip_smoke.py), and must
+        # never touch jax itself.
         log = None
         if args.log_dir:
             log = open(os.path.join(

@@ -1,64 +1,14 @@
-"""One shared jax.shard_map compatibility shim.
+"""The one spelling of shard_map this tree uses (jax 0.9).
 
-jax 0.8 moved shard_map out of jax.experimental and renamed the
-replication-check kwarg (check_rep -> check_vma). Every caller that wants
-to keep working across that boundary imports the pair from here instead of
-re-implementing the try/except — the kwarg MUST match what the resolved
-function actually accepts, which is decided by inspecting its signature
-(ADVICE r5: there is a jax window where the top-level `jax.shard_map`
-exists but still takes check_rep, so import location alone is not a
-reliable proxy for the kwarg spelling).
+``jax.shard_map`` with the ``check_vma`` keyword, and ``lax.axis_size``
+for the static size of a named mesh axis inside mapped code.  Callers
+import the three names from here so the spelling lives in one place.
 """
 
-import inspect
+from jax import shard_map
+from jax.lax import axis_size
 
-try:
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax layout
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _takes_check_vma(fn):
-    try:
-        params = inspect.signature(fn).parameters
-    except (TypeError, ValueError):
-        # unsignaturable (C accelerated / wrapped): assume the modern
-        # spelling, which every jax that hides the signature also uses
-        return True
-    if any(p.kind is inspect.Parameter.VAR_KEYWORD
-           for p in params.values()):
-        return "check_rep" not in params
-    return "check_vma" in params
-
-
-if _takes_check_vma(_shard_map):
-    shard_map = _shard_map
-
-    #: kwargs disabling the output-replication check, matching the signature
-    NO_CHECK = {"check_vma": False}
-else:
-    NO_CHECK = {"check_rep": False}
-
-    def shard_map(*args, check_vma=None, **kwargs):
-        # accept the modern kwarg spelling and translate it, so callers
-        # written against jax>=0.8 work unchanged on the legacy API
-        if check_vma is not None:
-            kwargs.setdefault("check_rep", check_vma)
-        return _shard_map(*args, **kwargs)
-
-
-def axis_size(axis_name):
-    """Static size of a named mesh axis from inside shard_map.
-
-    ``lax.axis_size`` only exists in newer jax; on older releases
-    ``lax.psum(1, axis)`` constant-folds to the same static int (no
-    collective is emitted for a literal operand), so every mapped-code
-    caller (ring attention, MoE EP, mp_ops) resolves through here."""
-    from jax import lax
-
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
-
+#: kwargs disabling the output-replication check
+NO_CHECK = {"check_vma": False}
 
 __all__ = ["shard_map", "NO_CHECK", "axis_size"]

@@ -19,8 +19,6 @@ import time
 
 import numpy as np
 import jax
-import jax.export  # noqa: F401 — jax 0.4.x only binds jax.export on
-# explicit submodule import; attribute access alone raises AttributeError
 import jax.numpy as jnp
 
 from ..core.tensor import Tensor, Parameter

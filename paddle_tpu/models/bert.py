@@ -1,4 +1,4 @@
-"""BERT family (BASELINE.md config 2: BERT-base MLM pretrain; the reference
+"""BERT family (BASELINE.json config 2: BERT-base MLM pretrain; the reference
 hosts this in PaddleNLP). Encoder built from paddle_tpu.nn.TransformerEncoder
 so attention rides the same flash path."""
 

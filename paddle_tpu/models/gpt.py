@@ -1,5 +1,5 @@
 """Decoder-only transformer LM family — the ERNIE-3.5 / LLaMA-2 capability
-target (BASELINE.md configs). The reference keeps these in PaddleNLP
+target (BASELINE.json configs). The reference keeps these in PaddleNLP
 (ecosystem); the TPU build ships them in-repo as the flagship models.
 
 TPU-first design decisions:
@@ -58,7 +58,7 @@ class GPTConfig:
         return self.num_key_value_heads or self.num_attention_heads
 
 
-# BASELINE.md model configs
+# BASELINE.json model configs
 ERNIE_7B = GPTConfig(
     vocab_size=32000, hidden_size=4096, intermediate_size=11008,
     num_hidden_layers=32, num_attention_heads=32, max_position_embeddings=4096,

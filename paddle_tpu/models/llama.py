@@ -1,4 +1,4 @@
-"""LLaMA model family (BASELINE.md: LLaMA-2-13B stage-3+recompute config).
+"""LLaMA model family (BASELINE.json: LLaMA-2-13B stage-3+recompute config).
 
 The decoder recipe (pre-norm RMSNorm, RoPE, SwiGLU, optional GQA) is shared
 with the flagship implementation in models/gpt.py; this module gives it the

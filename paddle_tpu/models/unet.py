@@ -1,4 +1,4 @@
-"""Stable-Diffusion-style conditional UNet (BASELINE.md config 5; the
+"""Stable-Diffusion-style conditional UNet (BASELINE.json config 5; the
 reference hosts it in ppdiffusers). Kept at SD-1.x topology but
 parameterized so the bench can scale it.
 

@@ -52,16 +52,11 @@ def _sdpa_ref(q, k, v, mask=None, dropout_p=0.0, causal=False, scale=None, key=N
 
 
 def _use_pallas(q_shape, head_dim):
-    try:
-        import jax
-
-        if jax.default_backend() != "tpu":
-            return False
-        # long-enough seq; non-lane-aligned head dims (<=256) are padded
-        # to 128 lanes by ops.flash_attention (free on the MXU)
-        return head_dim <= 256 and q_shape[1] >= 128
-    except Exception:
+    if jax.default_backend() != "tpu":
         return False
+    # long-enough seq; non-lane-aligned head dims (<=256) are padded
+    # to 128 lanes by ops.flash_attention (free on the MXU)
+    return head_dim <= 256 and q_shape[1] >= 128
 
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None, dropout_p=0.0,

@@ -17,8 +17,7 @@ compile, capturing what the compiler itself knows about the program:
 
 Cards live in a process-wide :class:`ProgramCardRegistry` keyed by
 ``(fn, signature-hash)`` so repeated engine construction with the same
-shapes never re-probes (the probe costs one extra XLA compile — see
-``capture()``).  The registry publishes ``compile.*`` gauges per card
+shapes never re-probes.  The registry publishes ``compile.*`` gauges per card
 (``NaN`` where an analysis is unavailable on the backend — the
 exposition format has a spelling for that, and dashboards should see
 "unknown", not 0), feeds the ``/debug/programs`` telemetry endpoint,
@@ -65,7 +64,9 @@ def _nan_if_none(v):
 
 
 class ProgramCard:
-    """The cost dossier of ONE compiled program."""
+    """The cost dossier of ONE compiled program.  ``backend`` is the
+    ``device_kind`` of the device it was compiled for ("cpu" on the
+    CPU) — the key ``peaks.PEAKS`` and the bandwidth lookups take."""
 
     __slots__ = ("fn", "key", "backend", "flops", "bytes_accessed",
                  "compile_seconds", "donated_bytes", "argument_bytes",
@@ -217,37 +218,27 @@ def analyze_lowered(lowered, deep=False):
     """Extract (flops, bytes_accessed, memory-stats dict, source) from a
     ``jax.stages.Lowered``.
 
-    ``deep=True`` compiles the program and reads the executable's
-    analyses (optimized HLO plus ``memory_analysis`` — the
-    train_step.cost_analysis probe pattern; ``lowered.compile()`` may
-    re-run XLA, which is why callers memoize cards process-wide and
-    only go deep on accelerator backends).  ``deep=False`` stays on the
-    HLO-level ``lowered.cost_analysis()`` — no extra compile, same
-    flops/bytes-accessed numbers on CPU, but no memory stats.  Returns
-    all-None when the backend offers neither."""
-    cost = mem = None
-    source = None
+    ``deep=True`` reads the executable's analyses (optimized HLO plus
+    ``memory_analysis``).  ``lowered.compile()`` does not run XLA again
+    when the jitted function has already been called with these
+    arguments: the ``Lowered`` and the call share one lowering, which
+    keeps its executable — so callers lower, call, then analyze.
+    ``deep=False`` stays on the HLO-level ``lowered.cost_analysis()`` —
+    same flops/bytes-accessed numbers on CPU, but no memory stats.  jax
+    returns None for an analysis the backend does not offer; so does
+    this (all-None when it offers neither).  Anything else raises."""
+    cost = mem = source = None
     if deep:
-        try:
-            compiled = lowered.compile()
-        except Exception:
-            compiled = None
-        if compiled is not None:
-            try:
-                cost = _scalar_analysis(compiled.cost_analysis())
-                source = "compiled"
-            except Exception:
-                cost = None
-            try:
-                mem = compiled.memory_analysis()
-            except Exception:
-                mem = None
+        compiled = lowered.compile()
+        cost = _scalar_analysis(compiled.cost_analysis())
+        source = "compiled" if cost is not None else None
+        mem = compiled.memory_analysis()
     if cost is None:
         try:
             cost = _scalar_analysis(lowered.cost_analysis())
-            source = "lowered" if cost is not None else None
-        except Exception:
+        except NotImplementedError:   # a Lowered with no analysis at all
             cost = None
+        source = "lowered" if cost is not None else None
     flops = bytes_accessed = None
     if cost:
         flops = cost.get("flops")
@@ -264,12 +255,11 @@ def analyze_lowered(lowered, deep=False):
 
 def capture(fn_name, key, lowered, compile_seconds=0.0, donated_bytes=0,
             meta=None, backend="", registry=None, deep=None, comms=None):
-    """Build + record one ProgramCard from a ``Lowered``; never raises
-    (a backend without analyses still yields a card with Nones, and any
-    probe failure degrades the same way).  ``deep=None`` auto-selects:
-    the compile-probe (memory stats, optimized-HLO cost) on accelerator
-    backends, the free HLO-level estimate on cpu — so test suites never
-    pay a second XLA compile per program.
+    """Build + record one ProgramCard from a ``Lowered``.  A backend
+    without analyses still yields a card with Nones; a probe that fails
+    for any other reason raises.  ``deep=None`` auto-selects: the
+    executable's analyses (memory stats, optimized-HLO cost) on
+    accelerator backends, the HLO-level estimate on cpu.
 
     ``comms`` (phase 4) attaches a collective census to the card: pass
     the ``comms.CommsReport`` of the traced program (its ``comms.*``
@@ -277,12 +267,8 @@ def capture(fn_name, key, lowered, compile_seconds=0.0, donated_bytes=0,
     reg = registry if registry is not None else _default_registry
     if deep is None:
         deep = backend not in ("", "cpu")
-    try:
-        flops, bytes_accessed, stats, source = analyze_lowered(
-            lowered, deep=deep)
-    except Exception:                # pragma: no cover - defensive
-        flops = bytes_accessed = source = None
-        stats = {}
+    flops, bytes_accessed, stats, source = analyze_lowered(
+        lowered, deep=deep)
     if comms is not None and hasattr(comms, "to_json"):
         try:
             comms = comms.publish().to_json()

@@ -47,6 +47,39 @@ def _xla_flash(q, k, v, causal, scale):
     return jnp.einsum("bhst,bthd->bshd", p.astype(v.dtype), v)
 
 
+#: fleet's mesh-axis names (distributed/topology.py): a sharded program
+#: splits the batch over the data axes and the heads over tensor parallel
+_BATCH_AXES = ("dp", "sharding")
+_HEAD_AXIS = "mp"
+
+
+def _per_shard(kernel, q, k, v):
+    """Run ``kernel(q, k, v)`` ([B, S, H, D] in and out) per shard where
+    the enclosing program is sharded over a mesh.
+
+    The SPMD partitioner refuses a Mosaic kernel ("cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    under a mesh (``jax.set_mesh``; TrainStep enters it when its state is
+    sharded) the call is a shard_map that is manual over every axis the
+    partitioner would otherwise own: batch over the data axes, heads over
+    'mp' — attention is independent along both — and replicated over the
+    rest.  An axis that does not divide its dimension is left out.
+    Outside a mesh, and inside a shard_map that is already manual over
+    every axis, the kernel is called as it is."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a: mesh.shape[a] for a in mesh.auto_axes}
+    if not auto:
+        return kernel(q, k, v)
+    batch = tuple(a for a in _BATCH_AXES if a in auto)
+    while batch and q.shape[0] % math.prod(auto[a] for a in batch):
+        batch = batch[:-1]
+    heads = _HEAD_AXIS if (_HEAD_AXIS in auto
+                           and k.shape[2] % auto[_HEAD_AXIS] == 0) else None
+    spec = jax.sharding.PartitionSpec(batch or None, None, heads, None)
+    return jax.shard_map(kernel, in_specs=(spec,) * 3, out_specs=spec,
+                         axis_names=set(auto), check_vma=False)(q, k, v)
+
+
 def flash_attention_arrays(q, k, v, causal=False, scale=None):
     """Array-level entry used by both the Tensor wrapper and jitted models.
 
@@ -63,14 +96,12 @@ def flash_attention_arrays(q, k, v, causal=False, scale=None):
 
         if d % 128:
             dp = -(-d // 128) * 128
-            s = scale if scale is not None else 1.0 / math.sqrt(d)
+            scale = scale if scale is not None else 1.0 / math.sqrt(d)
             pad = [(0, 0)] * 3 + [(0, dp - d)]
-            out = pallas_flash(jnp.pad(q, pad), jnp.pad(k, pad),
-                               jnp.pad(v, pad), causal=causal, scale=s,
-                               interpret=False)
-            return out[..., :d]
-        return pallas_flash(q, k, v, causal=causal, scale=scale,
-                            interpret=False)
+            q, k, v = (jnp.pad(x, pad) for x in (q, k, v))
+        kernel = functools.partial(pallas_flash, causal=causal, scale=scale,
+                                   interpret=False)
+        return _per_shard(kernel, q, k, v)[..., :d]
     return _xla_flash(q, k, v, causal, scale)
 
 

@@ -18,13 +18,27 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import rows_under_budget
+
 
 def _interpret_default():
     return jax.default_backend() != "tpu"
 
 
-def _row_block(n):
-    return min(256, n)
+def _row_block(n, h, dtype):
+    """Rows per block for an [n, h] operand, or all n rows when there are
+    fewer.  The backward kernels are the widest: x, g and dx blocks
+    double-buffered in the input dtype plus about two live f32
+    temporaries per element (the TPU compiler counted 17.3 B/element for
+    bf16 at hidden 4096)."""
+    return min(rows_under_budget(h * (6 * jnp.dtype(dtype).itemsize + 8)),
+               n)
+
+
+def _pad_rows(x2):
+    """Pad [n, h] up to a whole number of row blocks."""
+    pad = (-x2.shape[0]) % _row_block(*x2.shape, x2.dtype)
+    return jnp.pad(x2, ((0, pad), (0, 0))) if pad else x2
 
 
 # --------------------------------------------------------------- layer_norm
@@ -74,7 +88,7 @@ def _ln_bwd_kernel(x_ref, w_ref, mean_ref, rstd_ref, g_ref,
 
 def _ln_call_fwd(x2, w, b, eps, interpret):
     n, h = x2.shape
-    bn = _row_block(n)
+    bn = _row_block(n, h, x2.dtype)
     grid = (pl.cdiv(n, bn),)
     return pl.pallas_call(
         functools.partial(_ln_fwd_kernel, eps=eps),
@@ -107,10 +121,8 @@ def layer_norm(x, weight, bias, eps=1e-5, interpret=None):
     h = x.shape[-1]
     x2 = x.reshape(-1, h)
     n = x2.shape[0]
-    pad = (-n) % _row_block(n)
-    if pad:
-        x2 = jnp.pad(x2, ((0, pad), (0, 0)))
-    y, _, _ = _ln_call_fwd(x2, weight, bias, eps, interpret)
+    xp = _pad_rows(x2)
+    y, _, _ = _ln_call_fwd(xp, weight, bias, eps, interpret)
     return y[:n].reshape(x.shape)
 
 
@@ -120,8 +132,7 @@ def _ln_vjp_fwd(x, weight, bias, eps, interpret):
     h = x.shape[-1]
     x2 = x.reshape(-1, h)
     n = x2.shape[0]
-    pad = (-n) % _row_block(n)
-    xp = jnp.pad(x2, ((0, pad), (0, 0))) if pad else x2
+    xp = _pad_rows(x2)
     y, mean, rstd = _ln_call_fwd(xp, weight, bias, eps, interpret)
     return y[:n].reshape(x.shape), (xp, weight, mean, rstd, x.shape)
 
@@ -136,7 +147,7 @@ def _ln_vjp_bwd(eps, interpret, saved, g):
     n = g2.shape[0]
     if n_pad != n:
         g2 = jnp.pad(g2, ((0, n_pad - n), (0, 0)))
-    bn = _row_block(n_pad)
+    bn = _row_block(n_pad, h, xp.dtype)
     n_blocks = pl.cdiv(n_pad, bn)
     dx, dw, db = pl.pallas_call(
         functools.partial(_ln_bwd_kernel, n_blocks=n_blocks),
@@ -214,9 +225,8 @@ def _rms_fwd_call(x, weight, eps, interpret):
     h = x.shape[-1]
     x2 = x.reshape(-1, h)
     n = x2.shape[0]
-    pad = (-n) % _row_block(n)
-    xp = jnp.pad(x2, ((0, pad), (0, 0))) if pad else x2
-    bn = _row_block(xp.shape[0])
+    xp = _pad_rows(x2)
+    bn = _row_block(*xp.shape, xp.dtype)
     y, rstd = pl.pallas_call(
         functools.partial(_rms_fwd_kernel, eps=eps),
         grid=(pl.cdiv(xp.shape[0], bn),),
@@ -252,7 +262,7 @@ def _rms_vjp_bwd(eps, interpret, saved, g):
     n = g2.shape[0]
     if n_pad != n:
         g2 = jnp.pad(g2, ((0, n_pad - n), (0, 0)))
-    bn = _row_block(n_pad)
+    bn = _row_block(n_pad, h, xp.dtype)
     n_blocks = pl.cdiv(n_pad, bn)
     dx, dw = pl.pallas_call(
         functools.partial(_rms_bwd_kernel, n_blocks=n_blocks),
@@ -290,15 +300,15 @@ _GN_VMEM_BUDGET = 256 * 1024  # f32 elements per block (~1MB)
 def _gn_group_block(g, row):
     """Largest divisor of g whose [gb, row] f32 block stays under the
     budget — bounds every VMEM buffer independent of channel count (the
-    UNet up-blocks reach C=2560 after skip concats)."""
-    budget = _GN_VMEM_BUDGET
-    gb = g
-    while gb > 1 and gb * row > budget:
-        d = 2
-        while gb % d and d <= gb:
-            d += 1
-        gb //= d
-    return gb
+    UNet up-blocks reach C=2560 after skip concats).  Only multiples of
+    8 (and g itself) are candidates: Mosaic refuses a block whose
+    sublane dimension is neither a multiple of 8 nor the array's own.
+    The smallest candidate is the floor; `group_norm_supported` keeps
+    shapes whose floor does not compile off this kernel."""
+    cands = [gb for gb in range(1, g + 1)
+             if g % gb == 0 and (gb % 8 == 0 or gb == g)]
+    fits = [gb for gb in cands if gb * row <= _GN_VMEM_BUDGET]
+    return max(fits) if fits else min(cands)
 
 
 def _gn_fwd_kernel(x_ref, w_ref, b_ref, y_ref, mean_ref, rstd_ref, *, eps):
@@ -452,12 +462,14 @@ group_norm.defvjp(_gn_vjp_fwd, _gn_vjp_bwd)
 
 
 def group_norm_supported(x_shape, num_groups):
-    """True when channels split evenly into groups and a single group row
-    fits the per-block VMEM budget (group-blocking handles everything
-    above that)."""
-    if len(x_shape) < 3 or x_shape[1] % num_groups:
+    """True when channels split evenly into groups, the groups into
+    blocks of 8 (see `_gn_group_block`), and the smallest such block fits
+    VMEM: an [8, row] block at twice the budget is the largest whose f32
+    backward the v5e compiler accepted."""
+    if (len(x_shape) < 3 or x_shape[1] % num_groups
+            or num_groups % 8):
         return False
     row = x_shape[1] // num_groups
     for s in x_shape[2:]:
         row *= s
-    return row <= _GN_VMEM_BUDGET
+    return 8 * row <= 2 * _GN_VMEM_BUDGET

@@ -108,6 +108,7 @@ from ..core.tensor import Tensor
 from ..observability import events as _obs_events
 from ..observability import memory as _obs_memory
 from ..observability import metrics as _obs_metrics
+from ..observability import peaks as _obs_peaks
 from ..observability import profiling as _obs_profiling
 from ..observability import tracing as _obs_tracing
 from ..observability.span import span as _obs_span
@@ -265,10 +266,10 @@ class CompiledFn:
     program for a :class:`~paddle_tpu.observability.profiling
     .ProgramCard` — XLA cost/memory analysis, compile seconds, donated
     bytes, and whatever static metadata ``meta_fn(args)`` supplies
-    (the engine passes the bucket key).  The probe's
-    ``lowered.compile()`` may re-run XLA (the executable cache does not
-    absorb it on every backend), so cards are memoized PROCESS-WIDE by
-    (name, signature): a second engine with the same shapes pays
+    (the engine passes the bucket key).  The probe reads the
+    executable the dispatch itself compiled (see
+    ``profiling.analyze_lowered``), and cards are memoized PROCESS-WIDE
+    by (name, signature): a second engine with the same shapes pays
     nothing.  ``self.last_card`` tracks the card of the most recent
     dispatch (hit or miss) — the engine's per-dispatch cost model."""
 
@@ -353,29 +354,28 @@ class CompiledFn:
             card = _obs_profiling.default_registry().get(self._name, key)
             if card is None:
                 donated = self._donated_bytes(args)
-                try:
-                    lowered = self._jit.lower(*args)
-                except Exception:    # pragma: no cover - defensive
-                    lowered = None
+                lowered = self._jit.lower(*args)
         t0 = time.perf_counter()
         try:
-            return self._jit(*args)
+            out = self._jit(*args)
         finally:
             dt = time.perf_counter() - t0
             _COMPILE_COUNT.inc(fn=self._name)
             _COMPILE_SECONDS.observe(dt, fn=self._name)
             _obs_events.end("jit.compile", cat="serving", fn=self._name,
                             seconds=round(dt, 9))
-            if self._capture_cards:
-                if card is None and lowered is not None:
-                    card = _obs_profiling.capture(
-                        self._name, key, lowered, compile_seconds=dt,
-                        donated_bytes=donated, meta=self._meta(args),
-                        backend=jax.default_backend())
-                if card is not None:
-                    card.dispatches += 1
-                    self.cards[sig] = card
-                self.last_card = card
+        if self._capture_cards:
+            if card is None:
+                # after the call, so the card reads the executable the
+                # call compiled and nothing compiles twice
+                card = _obs_profiling.capture(
+                    self._name, key, lowered, compile_seconds=dt,
+                    donated_bytes=donated, meta=self._meta(args),
+                    backend=_obs_peaks.current_device_kind())
+            card.dispatches += 1
+            self.cards[sig] = card
+            self.last_card = card
+        return out
 
 
 @dataclass
@@ -2479,7 +2479,8 @@ class Engine:
             return True
         recompute_s = (self._prefill_dispatch_s
                        / self._prefill_tokens_dispatched) * n_tokens
-        bw = _obs_memory.host_device_bandwidth_gbs(jax.default_backend())
+        bw = _obs_memory.host_device_bandwidth_gbs(
+            _obs_peaks.current_device_kind())
         upload_s = n_blocks * self.pool.bytes_per_block / (bw * 1e9)
         return upload_s < recompute_s
 
@@ -2788,7 +2789,7 @@ class Engine:
             if self._decode.misses == misses0:
                 _obs_memory.publish_roofline(
                     self._profiler_name, h, card.bytes_accessed,
-                    dt_disp, jax.default_backend())
+                    dt_disp, _obs_peaks.current_device_kind())
         return toks
 
     def step(self, horizon=None):

@@ -59,8 +59,8 @@ class TestWireModel:
     def test_modeled_seconds_uses_datasheet(self):
         rep = comms.CommsReport()
         rep.add("psum", "dp", 1, 1 << 30, 8)  # one 1-GiB psum on an 8-ring
-        secs = comms.modeled_comms_seconds(rep, "tpu")
-        bw = comms.interconnect_bandwidth_gbs("tpu", tier="ici")
+        secs = comms.modeled_comms_seconds(rep, "TPU v5 lite")
+        bw = comms.interconnect_bandwidth_gbs("TPU v5 lite", tier="ici")
         expect = comms.wire_bytes("psum", 8, 1 << 30) / (bw * 1e9)
         assert secs == pytest.approx(expect)
 

@@ -50,41 +50,21 @@ class TestTopology:
 
 
 class TestShardMapCompat:
-    def test_kwarg_detected_by_signature_not_import_location(self):
-        """ADVICE r5: there is a jax window where top-level jax.shard_map
-        exists but still takes check_rep — the kwarg spelling must come
-        from the resolved function's signature, never from which import
-        succeeded."""
-        from paddle_tpu.distributed.shard_map_compat import (
-            NO_CHECK, _takes_check_vma, shard_map as resolved,
-        )
-
-        def modern(f, mesh, in_specs, out_specs, check_vma=True):
-            pass
-
-        def legacy(f, mesh, in_specs, out_specs, check_rep=True):
-            pass
-
-        def legacy_kw(f, mesh, in_specs, out_specs, check_rep=True, **kw):
-            pass
-
-        def opaque(*args, **kwargs):
-            pass
-
-        assert _takes_check_vma(modern)
-        assert not _takes_check_vma(legacy)
-        assert not _takes_check_vma(legacy_kw)
-        assert _takes_check_vma(opaque)      # unsignaturable → modern
-        # NO_CHECK's spelling agrees with whatever was resolved, and the
-        # resolved shard_map accepts it (the legacy wrapper translates)
-        assert len(NO_CHECK) == 1
-        assert set(NO_CHECK) <= {"check_vma", "check_rep"}
+    def test_names_are_the_jax_0_9_spelling(self):
+        """The module is the one place the shard_map spelling lives: the
+        resolved function is ``jax.shard_map`` itself, it accepts the
+        NO_CHECK keyword, and ``axis_size`` is ``lax.axis_size``."""
         import inspect as _inspect
 
+        from paddle_tpu.distributed.shard_map_compat import (
+            NO_CHECK, axis_size, shard_map as resolved,
+        )
+
+        assert resolved is jax.shard_map
+        assert axis_size is jax.lax.axis_size
+        assert NO_CHECK == {"check_vma": False}
         params = _inspect.signature(resolved).parameters
-        has_kw = any(p.kind is _inspect.Parameter.VAR_KEYWORD
-                     for p in params.values())
-        assert has_kw or all(k in params for k in NO_CHECK)
+        assert all(k in params for k in NO_CHECK)
 
 
 class TestCollectives:

