@@ -163,9 +163,9 @@ def _payload_bytes(obj):
 
 
 def save(obj, path, protocol=4, **configs):
-    # checkpoint saves land on the observability timeline (begin/end pair
-    # + a duration histogram), so "why did step time spike" is answerable
-    # when the answer is "a checkpoint flushed"
+    # checkpoint saves land in the span log (one record a save, and the
+    # count and seconds of ``span.seconds``), so "why did step time
+    # spike" is answerable when the answer is "a checkpoint flushed"
     from ..observability.span import span as _obs_span
 
     d = os.path.dirname(path)
@@ -173,9 +173,8 @@ def save(obj, path, protocol=4, **configs):
         os.makedirs(d, exist_ok=True)
     saveable = _to_saveable(obj)
     nbytes = _payload_bytes(saveable)
-    with _obs_span("checkpoint.save", cat="io",
-                   event_args={"path": str(path),
-                               "payload_bytes": nbytes}):
+    with _obs_span("checkpoint.save", cat="io", path=str(path),
+                   payload_bytes=nbytes):
         if nbytes >= _CONTAINER_THRESHOLD:
             _save_container(saveable, path, protocol)
             return

@@ -23,6 +23,7 @@ from ..core.tensor import Tensor
 from ..core import tape as _tape
 from ..core import random_state
 from ..observability import metrics as _obs_metrics
+from ..observability.span import build as _obs_build, span as _obs_span
 
 # NOTE: jax dispatch is async — step_seconds is host wall time per
 # dispatched step, which converges to true step time whenever the caller
@@ -514,6 +515,18 @@ class TrainStep:
         return Tensor(losses)
 
     def __call__(self, *batch):
+        """One step, enqueued: returns without a block on the device (the
+        loss is a device array until the caller reads it).  The span log
+        gets ``train.step.enqueue`` a call, and the first call, which
+        builds the program, one record in the build table."""
+        building = contextlib.nullcontext() if self._jitted is not None \
+            else _obs_build("train_step", {
+                "batch": [list(getattr(b, "shape", ())) for b in batch]})
+        with _obs_span("train.step.enqueue",
+                       step=self.optimizer._step_count), building:
+            return self._step(*batch)
+
+    def _step(self, *batch):
         t0 = time.perf_counter()
         (sd, param_arrays, buffer_arrays, opt_states, lr, rng_key,
          scaler_state, batch_arrays) = self._marshal(*batch)

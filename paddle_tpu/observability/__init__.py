@@ -11,14 +11,18 @@ serving queue" — from one process-wide place.
 Three layers (see each module's docstring):
 
 * :mod:`~paddle_tpu.observability.metrics` — typed Counter / Gauge /
-  Histogram registry with label sets; ``snapshot()`` (nested JSON) and
+  Histogram / Summary registry with label sets; ``snapshot()`` (nested JSON) and
   ``render_prometheus()`` (text exposition); absorbs the PR 2
   ``profiler.counters()`` provider registry.
 * :mod:`~paddle_tpu.observability.events` — bounded ring-buffer
   structured event log with chrome-trace/Perfetto JSON export.
-* :mod:`~paddle_tpu.observability.span` — ``span(name, **labels)``:
-  one context manager emitting a ``jax.profiler.TraceAnnotation``, a
-  histogram observation, and a begin/end timeline pair.
+* :mod:`~paddle_tpu.observability.span` — the span log:
+  ``span(name, rid=None, **args)`` writes one record a span into the
+  events ring when it ends (start and duration in ns on the profiler's
+  clock, thread, cause, request id, arguments), keeps count and seconds
+  per name (``span.seconds``) and opens a ``jax.profiler
+  .TraceAnnotation``; ``build(program, key)`` keeps one record a
+  program build, by phase.
 
 Phase 2 (request-scoped + externally visible):
 
@@ -42,8 +46,7 @@ Phase 3 (the performance observatory):
   engine's cost model for per-request attribution.
 * :mod:`~paddle_tpu.observability.memory` — device-memory ledger
   reconciling component-accounted bytes against ``jax.live_arrays()``
-  (leak-detector delta) plus the backend-bandwidth probe behind the
-  live achieved-vs-roofline gauge.
+  (leak-detector delta) plus the backend-bandwidth lookup.
 * :mod:`~paddle_tpu.observability.regression` — the bench-regression
   gate comparing a fresh bench run against the committed
   DECODE_BENCH.json (``check-bench`` CLI mode, run in CI; phase 4 adds

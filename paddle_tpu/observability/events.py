@@ -2,12 +2,27 @@
 
 Every subsystem appends typed host events here — compile begin/end with
 the aval signature and wall seconds, retrace causes, dataloader stalls,
-serving slot alloc/retire/EOS, checkpoint saves — into ONE process-wide
-ring buffer (old events fall off; recording never blocks or grows
-unboundedly).
+serving slot alloc/retire/EOS — into ONE process-wide ring buffer (old
+events fall off; recording never blocks or grows unboundedly).  A
+finished ``observability.span`` is ONE ``COMPLETE`` record in the same
+ring: name, start and duration in nanoseconds, thread, the span that was
+open on that thread when it began (``cause``), a request id (``id``) and
+a small dict of arguments.
+
+The clock is ``time.time_ns()``: CLOCK_REALTIME, which is also what
+``jax.profiler`` stamps its host events with.  A trace file
+(``.xplane.pb``) holds its events relative to the session's start, which
+it gives as the ``profile_start_time`` stat (epoch nanoseconds): a
+span's ``start_ns`` less that stat is its place in the trace.  PERF.md
+gives the offset measured on the chip (same span in the log and in the
+trace).  A record also says whether a trace was running when the span
+began (``traced``), so the traced part of a run can be found in the log
+without the trace file.  The ring holds 65,536 records: a serving engine under load
+writes some 800 a second (at most 14 an engine step, and one a streamed
+frame), so more than a minute of it.
 
 ``export_chrome_trace()`` emits the Chrome Trace Event JSON format
-(``{"traceEvents": [...]}``, ts in microseconds, ``B``/``E``/``i``
+(``{"traceEvents": [...]}``, ts in microseconds, ``X``/``B``/``E``/``i``
 phases), loadable in ``chrome://tracing`` / Perfetto — drop it next to a
 ``jax.profiler`` device trace and the host timeline interleaves with the
 XLA one.
@@ -21,7 +36,7 @@ import os
 import threading
 import time
 
-DEFAULT_CAPACITY = 4096
+DEFAULT_CAPACITY = 65536
 
 #: phases (chrome trace event ``ph`` values)
 BEGIN = "B"
@@ -36,22 +51,33 @@ ASYNC_END = "e"
 
 
 class Event:
-    """One timeline entry. ``ts`` is ``time.time()`` seconds (wall clock,
-    so host events line up with device-trace timestamps); ``dur`` is
-    seconds for COMPLETE events, None otherwise."""
+    """One timeline entry. ``ts`` is ``time.time()`` seconds (the wall
+    clock, which is the profiler's too); ``dur`` is seconds for COMPLETE
+    events, None otherwise.  A span's record also carries its exact
+    ``start_ns``/``dur_ns`` (``ts``/``dur`` are derived from them), its
+    ``cause`` and whether a profiler trace was running when it began
+    (``traced``)."""
 
-    __slots__ = ("name", "phase", "ts", "dur", "cat", "tid", "args", "id")
+    __slots__ = ("name", "phase", "ts", "dur", "cat", "tid", "args", "id",
+                 "cause", "start_ns", "dur_ns", "traced")
 
     def __init__(self, name, phase=INSTANT, ts=None, dur=None, cat="host",
-                 tid=None, args=None, id=None):
+                 tid=None, args=None, id=None, cause=None, start_ns=None,
+                 dur_ns=None, traced=False):
         self.name = name
         self.phase = phase
+        if start_ns is not None:
+            ts, dur = start_ns / 1e9, dur_ns / 1e9
         self.ts = time.time() if ts is None else ts
         self.dur = dur
         self.cat = cat
         self.tid = threading.get_ident() if tid is None else tid
         self.args = dict(args) if args else {}
         self.id = id
+        self.cause = cause
+        self.start_ns = start_ns
+        self.dur_ns = dur_ns
+        self.traced = traced
 
     def to_chrome(self):
         ev = {
@@ -68,8 +94,11 @@ class Event:
             ev["s"] = "t"                  # thread-scoped instant
         if self.id is not None:
             ev["id"] = str(self.id)
-        if self.args:
-            ev["args"] = {k: _jsonable(v) for k, v in self.args.items()}
+        args = self.args
+        if self.cause is not None:
+            args = dict(args, cause=self.cause)
+        if args:
+            ev["args"] = {k: _jsonable(v) for k, v in args.items()}
         return ev
 
     def __repr__(self):
@@ -101,15 +130,17 @@ class EventLog:
             self._ring = collections.deque(old[-capacity:],
                                            maxlen=int(capacity))
 
-    def record(self, name, phase=INSTANT, cat="host", dur=None, args=None,
-               ts=None, id=None):
-        ev = Event(name, phase=phase, ts=ts, dur=dur, cat=cat, args=args,
-                   id=id)
+    def append(self, ev):
         with self._lock:
             if len(self._ring) == self._ring.maxlen:
                 self._dropped += 1
             self._ring.append(ev)
         return ev
+
+    def record(self, name, phase=INSTANT, cat="host", dur=None, args=None,
+               ts=None, id=None):
+        return self.append(Event(name, phase=phase, ts=ts, dur=dur, cat=cat,
+                                 args=args, id=id))
 
     def begin(self, name, cat="host", **args):
         return self.record(name, phase=BEGIN, cat=cat, args=args)

@@ -1,4 +1,4 @@
-"""Device-memory ledger + online roofline (observability phase 3).
+"""Device-memory ledger + the backend-bandwidth lookup (observability phase 3).
 
 Two answers this module owns:
 
@@ -23,14 +23,10 @@ reconciles their sum against what JAX actually holds alive
 Reconciliation walks every live array, so it runs on demand
 (``Engine.stats()``, tests, dashboards) — not per decode step.
 
-**How close to the roofline is decode running?**  The bandwidth lookup
-lives here (so the live engine and the bench share one number): the
-published row of ``peaks.PEAKS`` for the chip's ``device_kind``, a
-one-shot 64 MiB memcpy probe for the CPU.  The
-engine combines a decode program card's bytes-accessed with its
-dispatch wall time and publishes
-``memory.roofline_utilization{engine,horizon}`` — the bench's
-``roofline_pct`` column as a LIVE gauge.
+**What bandwidth does this backend have?**  The lookup lives here (so
+the swap policy, the fleet simulator and the benches share one number):
+the published row of ``peaks.PEAKS`` for the chip's ``device_kind``, a
+one-shot 64 MiB memcpy probe for the CPU.
 """
 
 from __future__ import annotations
@@ -65,13 +61,6 @@ _HOST_ACCT = _metrics.gauge(
     "reconciliation — host numpy buffers never appear in "
     "jax.live_arrays(), so folding them into accounted_total_bytes "
     "would poison unaccounted/leak_delta")
-_ROOFLINE = _metrics.gauge(
-    "memory.roofline_utilization",
-    "achieved bytes/s of the last decode dispatch / backend bandwidth")
-_ACHIEVED = _metrics.gauge(
-    "memory.achieved_bandwidth_gbs",
-    "bytes-accessed of the last decode dispatch over its wall seconds")
-
 _BW_PROBED = {}
 _BW_LOCK = threading.Lock()
 
@@ -83,8 +72,7 @@ def backend_bandwidth_gbs(device_kind):
     device peak — gets a one-shot streaming-memcpy probe instead (64 MiB
     source, read+write counted, best of 4 passes — DRAM speed, not L3,
     at that footprint).  Memoized: the probe runs at most once per
-    process so the live gauge and every bench section agree on the
-    number."""
+    process so every caller agrees on the number."""
     row = _peaks.chip_peaks(device_kind)
     if row is not None:
         return row.hbm_gbs
@@ -137,19 +125,6 @@ def live_device_bytes():
         except Exception:            # deleted/donated buffers
             continue
     return total
-
-
-def publish_roofline(engine, horizon, bytes_accessed, wall_seconds,
-                     device_kind):
-    """One decode dispatch's achieved-vs-roofline utilization as live
-    gauges (called by the engine after each non-compiling dispatch)."""
-    if not bytes_accessed or wall_seconds <= 0:
-        return None
-    achieved = bytes_accessed / wall_seconds / 1e9
-    util = achieved / backend_bandwidth_gbs(device_kind)
-    _ACHIEVED.set(round(achieved, 4), engine=engine, horizon=horizon)
-    _ROOFLINE.set(round(util, 6), engine=engine, horizon=horizon)
-    return util
 
 
 class MemoryLedger:

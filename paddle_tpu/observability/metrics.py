@@ -1,14 +1,16 @@
 """Typed process-wide metrics registry (the unified replacement for the
 ad-hoc counter dicts PR 2 grew in ``paddle_tpu.profiler``).
 
-Three primitives, all label-aware and thread-safe:
+Four primitives, all label-aware and thread-safe:
 
 * ``Counter`` — monotonically increasing (compile counts, tokens
   generated, cache hits);
 * ``Gauge`` — set-to-current-value (queue depth, active slots);
 * ``Histogram`` — fixed cumulative buckets for the prometheus exposition
   PLUS a bounded reservoir of raw samples for exact p50/p95/p99
-  (compile seconds, step time, TTFT).
+  (compile seconds, step time, TTFT);
+* ``Summary`` — count and sum only (``span.seconds``: observed on every
+  span, so it pays for neither buckets nor a reservoir).
 
 Two exports:
 
@@ -245,6 +247,32 @@ class Histogram(Metric):
                 for k, v in sorted(self._values.items())}
 
 
+class Summary(Metric):
+    """Count and sum per label set and nothing else (the exposition
+    format's ``summary`` with no quantiles): two numbers that never
+    forget, for a family observed too often to pay for buckets and a
+    reservoir (``span.seconds``)."""
+
+    kind = "summary"
+
+    def _new_slot(self):
+        return [0, 0.0]
+
+    def observe(self, value, **labels):
+        slot = self._slot(labels)
+        with self._lock:
+            slot[0] += 1
+            slot[1] += value
+
+    def stats(self, **labels):
+        slot = self._values.get(_label_key(labels))
+        return None if slot is None else {"count": slot[0], "sum": slot[1]}
+
+    def snapshot_values(self):
+        return {_label_str(k): {"count": v[0], "sum": v[1]}
+                for k, v in sorted(self._values.items())}
+
+
 class Registry:
     """A named collection of metrics plus the legacy provider registry.
 
@@ -287,6 +315,9 @@ class Registry:
         return self._get_or_create(Histogram, name, help,
                                    buckets=buckets, reservoir=reservoir)
 
+    def summary(self, name, help=""):
+        return self._get_or_create(Summary, name, help)
+
     def get(self, name):
         return self._metrics.get(name)
 
@@ -295,13 +326,13 @@ class Registry:
 
     def value(self, name, /, **labels):
         """Convenience for tests/assertions: the scalar value (Counter/
-        Gauge) or stats dict (Histogram) for one (metric, label set).
-        ``name`` is positional-only so a label may itself be called
-        ``name`` (the span histogram's label scheme)."""
+        Gauge) or stats dict (Histogram, Summary) for one (metric, label
+        set).  ``name`` is positional-only so a label may itself be
+        called ``name`` (the span family's label scheme)."""
         m = self._metrics.get(name)
         if m is None:
             return None
-        if isinstance(m, Histogram):
+        if isinstance(m, (Histogram, Summary)):
             return m.stats(**labels)
         return m.value(**labels)
 
@@ -384,6 +415,12 @@ class Registry:
                         f"{_fmt_value(slot.sum)}")
                     lines.append(
                         f"{pname}_count{_label_prom(key)} {slot.count}")
+            elif isinstance(m, Summary):
+                for key in sorted(m._values):
+                    count, total = m._values[key]
+                    lines.append(f"{pname}_sum{_label_prom(key)} "
+                                 f"{_fmt_value(total)}")
+                    lines.append(f"{pname}_count{_label_prom(key)} {count}")
             else:
                 for key in sorted(m._values):
                     lines.append(
@@ -591,6 +628,10 @@ def histogram(name, help="", buckets=DEFAULT_BUCKETS,
               reservoir=DEFAULT_RESERVOIR):
     return _default_registry.histogram(name, help, buckets=buckets,
                                        reservoir=reservoir)
+
+
+def summary(name, help=""):
+    return _default_registry.summary(name, help)
 
 
 def value(name, /, **labels):

@@ -111,7 +111,7 @@ from ..observability import metrics as _obs_metrics
 from ..observability import peaks as _obs_peaks
 from ..observability import profiling as _obs_profiling
 from ..observability import tracing as _obs_tracing
-from ..observability.span import span as _obs_span
+from ..observability.span import build as _build, span as _span
 from .drafter import draft_tokens, forced_chain
 from .faults import (DEGRADE_LEVELS, FAULT_POOL_EXHAUSTED,
                      SITE_ENGINE_ADMIT, _SRV_DEGRADATION, _SRV_SHED)
@@ -260,7 +260,14 @@ class CompiledFn:
     misses == number of distinct length buckets.  Hits/misses also land
     on the typed registry (``jit.compile_count`` / ``jit.cache_hit``
     labeled ``fn=name``) and every miss leaves a retrace-cause event plus
-    a compile begin/end pair on the timeline.
+    a compile begin/end pair on the timeline, and ONE record in the
+    span log's build table (``observability.span.build``): the program's
+    name and bucket key (``meta_fn(args)``), the miss's total seconds,
+    and the part of them jax reports as tracing, lowering, backend
+    compile and persistent-cache retrieval, the rest being executable
+    load, the first run and the card probe below.  (``jit.compile``'s
+    one number is the first call's seconds: from a warm compile cache
+    everything but compiling.)
 
     With ``capture_cards=True`` every miss also probes the lowered
     program for a :class:`~paddle_tpu.observability.profiling
@@ -344,38 +351,40 @@ class CompiledFn:
                    else "new_input_signature"),
             cached_signatures=len(self._seen) - 1)
         _obs_events.begin("jit.compile", cat="serving", fn=self._name)
-        # lower BEFORE the call: on donating backends the call deletes
-        # the donated buffers, after which tracing them would fail.  A
-        # process-wide card for this exact program skips the probe.
-        lowered = card = None
-        donated = 0
-        if self._capture_cards:
-            key = self._card_key(sig)
-            card = _obs_profiling.default_registry().get(self._name, key)
-            if card is None:
-                donated = self._donated_bytes(args)
-                lowered = self._jit.lower(*args)
-        t0 = time.perf_counter()
-        try:
-            out = self._jit(*args)
-        finally:
-            dt = time.perf_counter() - t0
-            _COMPILE_COUNT.inc(fn=self._name)
-            _COMPILE_SECONDS.observe(dt, fn=self._name)
-            _obs_events.end("jit.compile", cat="serving", fn=self._name,
-                            seconds=round(dt, 9))
-        if self._capture_cards:
-            if card is None:
-                # after the call, so the card reads the executable the
-                # call compiled and nothing compiles twice
-                card = _obs_profiling.capture(
-                    self._name, key, lowered, compile_seconds=dt,
-                    donated_bytes=donated, meta=self._meta(args),
-                    backend=_obs_peaks.current_device_kind())
-            card.dispatches += 1
-            self.cards[sig] = card
-            self.last_card = card
-        return out
+        meta = self._meta(args)
+        with _build(self._name, meta):
+            # lower BEFORE the call: on donating backends the call deletes
+            # the donated buffers, after which tracing them would fail.  A
+            # process-wide card for this exact program skips the probe.
+            lowered = card = None
+            donated = 0
+            if self._capture_cards:
+                key = self._card_key(sig)
+                card = _obs_profiling.default_registry().get(self._name, key)
+                if card is None:
+                    donated = self._donated_bytes(args)
+                    lowered = self._jit.lower(*args)
+            t0 = time.perf_counter()
+            try:
+                out = self._jit(*args)
+            finally:
+                dt = time.perf_counter() - t0
+                _COMPILE_COUNT.inc(fn=self._name)
+                _COMPILE_SECONDS.observe(dt, fn=self._name)
+                _obs_events.end("jit.compile", cat="serving", fn=self._name,
+                                seconds=round(dt, 9))
+            if self._capture_cards:
+                if card is None:
+                    # after the call, so the card reads the executable the
+                    # call compiled and nothing compiles twice
+                    card = _obs_profiling.capture(
+                        self._name, key, lowered, compile_seconds=dt,
+                        donated_bytes=donated, meta=meta,
+                        backend=_obs_peaks.current_device_kind())
+                card.dispatches += 1
+                self.cards[sig] = card
+                self.last_card = card
+            return out
 
 
 @dataclass
@@ -1679,93 +1688,97 @@ class Engine:
         front (order preserved) to retry after running requests retire.
         An oversubscribed pool therefore defers admission instead of
         failing mid-prefill."""
-        self._expire_deadlines()
-        self._admit_deferred = False
-        if self.scheduler.queue_depth:
-            if self._degrade_level >= 3:
-                # ladder level 3: shed lowest-priority queued requests
-                # down to num_slots queued (resumed requests are never
-                # shed — their tokens are already streamed)
-                for req in self.scheduler.shed_victims(
-                        self.cache.num_slots):
-                    self._degrade_sheds += 1
-                    _SRV_SHED.inc(engine=self._profiler_name)
-                    self.abort(req, cause="shed")
-            if self.faults is not None:
-                spec = self.faults.fire(SITE_ENGINE_ADMIT,
-                                        scope=self._fault_scope)
-                if (spec is not None
-                        and spec.kind == FAULT_POOL_EXHAUSTED):
-                    # behave exactly like a dry pool: defer this whole
-                    # admission pass to the next horizon boundary
-                    self._admit_deferred = True
-                    return
-        # continuation chunks first: in-flight chunked prefills advance
-        # one chunk per boundary ahead of new admissions (their blocks
-        # are already partly written — finishing them frees capacity
-        # soonest and keeps TTFT ordering honest)
-        self._advance_chunks()
-        # tiered KV: promote host-arena state for the requests this
-        # admission pass could plausibly pop (the free slots plus the
-        # reorder window it may look past), so their admission becomes
-        # a prefix hit instead of a re-prefill
-        if self.host_tier is not None and self.scheduler.queue_depth:
-            window = self.cache.free_slots + self.config.reorder_window
-            for req in list(self.scheduler.queue)[:window]:
-                if self._swap_in(req) is None:
-                    # pool dry even after reclaim — a later request's
-                    # swap-in can't fare better, and pressing on would
-                    # only churn (each attempt's reclaim retry eats
-                    # LRU radix blocks, possibly an earlier request's
-                    # freshly grafted chain).  Host state is intact:
-                    # swap-in consumes nothing before its device
-                    # blocks are allocated.
+        with _span("engine.admit", requests=0) as sp:
+            before = self._prefill_requests
+            self._expire_deadlines()
+            self._admit_deferred = False
+            if self.scheduler.queue_depth:
+                if self._degrade_level >= 3:
+                    # ladder level 3: shed lowest-priority queued requests
+                    # down to num_slots queued (resumed requests are never
+                    # shed — their tokens are already streamed)
+                    for req in self.scheduler.shed_victims(
+                            self.cache.num_slots):
+                        self._degrade_sheds += 1
+                        _SRV_SHED.inc(engine=self._profiler_name)
+                        self.abort(req, cause="shed")
+                if self.faults is not None:
+                    spec = self.faults.fire(SITE_ENGINE_ADMIT,
+                                            scope=self._fault_scope)
+                    if (spec is not None
+                            and spec.kind == FAULT_POOL_EXHAUSTED):
+                        # behave exactly like a dry pool: defer this whole
+                        # admission pass to the next horizon boundary
+                        self._admit_deferred = True
+                        return
+            # continuation chunks first: in-flight chunked prefills advance
+            # one chunk per boundary ahead of new admissions (their blocks
+            # are already partly written — finishing them frees capacity
+            # soonest and keeps TTFT ordering honest)
+            self._advance_chunks()
+            # tiered KV: promote host-arena state for the requests this
+            # admission pass could plausibly pop (the free slots plus the
+            # reorder window it may look past), so their admission becomes
+            # a prefix hit instead of a re-prefill
+            if self.host_tier is not None and self.scheduler.queue_depth:
+                window = self.cache.free_slots + self.config.reorder_window
+                for req in list(self.scheduler.queue)[:window]:
+                    if self._swap_in(req) is None:
+                        # pool dry even after reclaim — a later request's
+                        # swap-in can't fare better, and pressing on would
+                        # only churn (each attempt's reclaim retry eats
+                        # LRU radix blocks, possibly an earlier request's
+                        # freshly grafted chain).  Host state is intact:
+                        # swap-in consumes nothing before its device
+                        # blocks are allocated.
+                        break
+            # while draining, the queue can only hold `resumed` requests
+            # (submit() refuses and drain() aborted the rest) — re-admitting
+            # them is finishing in-flight work, so admission proceeds
+            while self.cache.free_slots and self.scheduler.queue_depth:
+                batch = self.scheduler.pop_batch(
+                    self.cache.free_slots,
+                    bucket_of=self._admission_bucket)
+                if not batch:
                     break
-        # while draining, the queue can only hold `resumed` requests
-        # (submit() refuses and drain() aborted the rest) — re-admitting
-        # them is finishing in-flight work, so admission proceeds
-        while self.cache.free_slots and self.scheduler.queue_depth:
-            batch = self.scheduler.pop_batch(self.cache.free_slots,
-                                             bucket_of=self._admission_bucket)
-            if not batch:
-                break
-            need = sum(self._blocks_needed(r) for r in batch)
-            short = need - self.pool.free_blocks
-            while short > 0 and self.prefix.reclaim(short):
-                # reclaim may have evicted unpinned blocks this very
-                # batch counted as prefix hits (promoted or cached
-                # chains are fair LRU victims until acquire pins them),
-                # so re-derive the need against the post-reclaim radix
-                # and keep reclaiming until it stabilizes — each pass
-                # either closes the gap or strictly shrinks the set of
-                # unpinned blocks, so this terminates
                 need = sum(self._blocks_needed(r) for r in batch)
                 short = need - self.pool.free_blocks
-            if short > 0:
-                self.scheduler.queue.extendleft(reversed(batch))
-                if self.scheduler.running:
-                    break            # retry after retirements free blocks
-                # nothing running to wait for: admit the longest
-                # queue-head prefix of the batch that fits (same bucket,
-                # so it still prefills as one dispatch)
-                fit, free = [], self.pool.free_blocks
-                for r in batch:
-                    nb = self._blocks_needed(r)
-                    if nb > free:
-                        break
-                    free -= nb
-                    fit.append(r)
-                if not fit:
-                    raise RuntimeError(
-                        f"KV pool too small: the queue head alone needs "
-                        f"{self._blocks_needed(batch[0])} blocks, pool "
-                        f"has {self.pool.free_blocks} free and nothing "
-                        "is running to retire (raise kv_pool_blocks or "
-                        "free the prefix budget)")
-                for _ in fit:
-                    self.scheduler.queue.popleft()
-                batch = fit
-            self._prefill_batch(batch)
+                while short > 0 and self.prefix.reclaim(short):
+                    # reclaim may have evicted unpinned blocks this very
+                    # batch counted as prefix hits (promoted or cached
+                    # chains are fair LRU victims until acquire pins them),
+                    # so re-derive the need against the post-reclaim radix
+                    # and keep reclaiming until it stabilizes — each pass
+                    # either closes the gap or strictly shrinks the set of
+                    # unpinned blocks, so this terminates
+                    need = sum(self._blocks_needed(r) for r in batch)
+                    short = need - self.pool.free_blocks
+                if short > 0:
+                    self.scheduler.queue.extendleft(reversed(batch))
+                    if self.scheduler.running:
+                        break            # retry after retirements free blocks
+                    # nothing running to wait for: admit the longest
+                    # queue-head prefix of the batch that fits (same bucket,
+                    # so it still prefills as one dispatch)
+                    fit, free = [], self.pool.free_blocks
+                    for r in batch:
+                        nb = self._blocks_needed(r)
+                        if nb > free:
+                            break
+                        free -= nb
+                        fit.append(r)
+                    if not fit:
+                        raise RuntimeError(
+                            f"KV pool too small: the queue head alone needs "
+                            f"{self._blocks_needed(batch[0])} blocks, pool "
+                            f"has {self.pool.free_blocks} free and nothing "
+                            "is running to retire (raise kv_pool_blocks or "
+                            "free the prefix budget)")
+                    for _ in fit:
+                        self.scheduler.queue.popleft()
+                    batch = fit
+                self._prefill_batch(batch)
+                sp.args["requests"] = self._prefill_requests - before
 
     _admit = admit      # pre-horizon internal name, kept for callers
 
@@ -1871,58 +1884,59 @@ class Engine:
                           "prefix_hit_tokens": lease.matched_tokens})
 
         first_np, dfa = self._dispatch_prefill(entries, bucket, lanes)
-        name = self._profiler_name
-        self._prefill_requests += n
-        _SRV_PREFILL_REQS.inc(n, engine=name)
-        _SRV_PREFILL_BATCH.observe(n, engine=name)
+        with _span("engine.prefill.harvest"):
+            name = self._profiler_name
+            self._prefill_requests += n
+            _SRV_PREFILL_REQS.inc(n, engine=name)
+            _SRV_PREFILL_BATCH.observe(n, engine=name)
 
-        # cost attribution: the dispatch's program-card totals split
-        # evenly over the n REAL requests (padding lanes ride free but
-        # their work is part of serving these n), so per-request shares
-        # sum back to the engine's _program_* totals exactly
-        card = self._prefill.last_card
-        if card is not None:
-            for ev in admit_events:
-                if ev is not None:
-                    if card.flops is not None:
-                        ev["flops_est"] = card.flops / n
-                    if card.bytes_accessed is not None:
-                        ev["bytes_est"] = card.bytes_accessed / n
+            # cost attribution: the dispatch's program-card totals split
+            # evenly over the n REAL requests (padding lanes ride free but
+            # their work is part of serving these n), so per-request shares
+            # sum back to the engine's _program_* totals exactly
+            card = self._prefill.last_card
+            if card is not None:
+                for ev in admit_events:
+                    if ev is not None:
+                        if card.flops is not None:
+                            ev["flops_est"] = card.flops / n
+                        if card.bytes_accessed is not None:
+                            ev["bytes_est"] = card.bytes_accessed / n
 
-        # cache the new full blocks of every admitted prompt (chunked
-        # lanes: the blocks their first chunk just completed): the radix
-        # store takes shared references on the slot's freshly written
-        # private blocks — pure host-side refcounting, no data motion
-        for e in entries:
-            row = self.cache.tables[e["slot"]]
-            self.prefix.adopt(e["toks"][:e["start"] + e["take"]],
-                              e["lease"],
-                              block_of=lambda j, row=row: row[j])
+            # cache the new full blocks of every admitted prompt (chunked
+            # lanes: the blocks their first chunk just completed): the radix
+            # store takes shared references on the slot's freshly written
+            # private blocks — pure host-side refcounting, no data motion
+            for e in entries:
+                row = self.cache.tables[e["slot"]]
+                self.prefix.adopt(e["toks"][:e["start"] + e["take"]],
+                                  e["lease"],
+                                  block_of=lambda j, row=row: row[j])
 
-        for i, e in enumerate(entries):
-            req, lease, slot = e["req"], e["lease"], e["slot"]
-            hit = lease.matched_tokens
-            self._prefix_hit_tokens += hit
-            self._prompt_tokens += len(e["toks"])
-            if hit:
-                _SRV_PREFIX_HIT.inc(hit, engine=name)
-            if not e["final"]:
-                # chunked admission: first chunk written, no token
-                # sampled yet — register the continuation ledger and
-                # leave the lane decode-inactive
-                cover = e["start"] + e["take"]
-                self._chunked_requests += 1
-                self._chunk_count_total += 1
-                self._chunking[req.request_id] = _ChunkProgress(
-                    req, slot, lease, e["toks"], cover, chunks=1)
-                self._pos[slot] = cover
-                self._active[slot] = False
-                self._state_dirty = True
-                self._context_high_water = max(
-                    self._context_high_water, cover)
-                continue
-            self._finish_prefill_lane(req, slot, e["toks"],
-                                      int(first_np[i]), int(dfa[i]))
+            for i, e in enumerate(entries):
+                req, lease, slot = e["req"], e["lease"], e["slot"]
+                hit = lease.matched_tokens
+                self._prefix_hit_tokens += hit
+                self._prompt_tokens += len(e["toks"])
+                if hit:
+                    _SRV_PREFIX_HIT.inc(hit, engine=name)
+                if not e["final"]:
+                    # chunked admission: first chunk written, no token
+                    # sampled yet — register the continuation ledger and
+                    # leave the lane decode-inactive
+                    cover = e["start"] + e["take"]
+                    self._chunked_requests += 1
+                    self._chunk_count_total += 1
+                    self._chunking[req.request_id] = _ChunkProgress(
+                        req, slot, lease, e["toks"], cover, chunks=1)
+                    self._pos[slot] = cover
+                    self._active[slot] = False
+                    self._state_dirty = True
+                    self._context_high_water = max(
+                        self._context_high_water, cover)
+                    continue
+                self._finish_prefill_lane(req, slot, e["toks"],
+                                          int(first_np[i]), int(dfa[i]))
 
     def _dispatch_prefill(self, entries, bucket, lanes):
         """Build the lane arrays for a prefill dispatch (admission
@@ -1931,60 +1945,60 @@ class Engine:
         first-token array after the host sync, and the per-lane DFA
         admission states the dispatch ran with (callers advance the
         armed lanes' mirrors through them)."""
-        # lane arrays: real requests first, then padding lanes whose
-        # all-zero table rows route every write to scratch block 0
-        ids = np.zeros((lanes, bucket), np.int32)
-        lengths = np.ones(lanes, np.int32)
-        prefix_lens = np.zeros(lanes, np.int32)
-        tables = np.zeros((lanes, self._max_blocks), np.int32)
-        cow_src = np.zeros(lanes, np.int32)
-        cow_dst = np.zeros(lanes, np.int32)
-        counts = np.zeros(lanes, np.int32)
-        seeds = np.zeros(lanes, np.uint32)
-        temps = np.zeros(lanes, np.float32)
-        top_ks = np.zeros(lanes, np.int32)
-        top_ps = np.ones(lanes, np.float32)
-        # per-lane DFA admission states; 0 (accept-all sentinel) for
-        # free, padding, and non-final chunk lanes (whose sampled token
-        # is discarded)
-        dfa = np.zeros(lanes, np.int32)
-        for i, e in enumerate(entries):
-            req = e["req"]
-            if e["final"] and req.grammar is not None:
-                dfa[i] = self._dfa_admission_state(req)
-            window = e["toks"][e["start"]:e["start"] + e["take"]]
-            ids[i, :len(window)] = window
-            lengths[i] = len(window)
-            prefix_lens[i] = e["start"]
-            tables[i] = self.cache.tables[e["slot"]]
-            if e["cow"] is not None:
-                cow_src[i], cow_dst[i] = e["cow"]
-            if e["final"]:
-                counts[i] = max(0, req.n_generated - 1)
-            s = req.sampling
-            seeds[i] = np.uint32(s.seed)
-            temps[i] = s.temperature
-            top_ks[i] = s.top_k
-            top_ps[i] = s.top_p
+        with _span("engine.prefill.build", bucket=bucket, lanes=lanes):
+            # lane arrays: real requests first, then padding lanes whose
+            # all-zero table rows route every write to scratch block 0
+            ids = np.zeros((lanes, bucket), np.int32)
+            lengths = np.ones(lanes, np.int32)
+            prefix_lens = np.zeros(lanes, np.int32)
+            tables = np.zeros((lanes, self._max_blocks), np.int32)
+            cow_src = np.zeros(lanes, np.int32)
+            cow_dst = np.zeros(lanes, np.int32)
+            counts = np.zeros(lanes, np.int32)
+            seeds = np.zeros(lanes, np.uint32)
+            temps = np.zeros(lanes, np.float32)
+            top_ks = np.zeros(lanes, np.int32)
+            top_ps = np.ones(lanes, np.float32)
+            # per-lane DFA admission states; 0 (accept-all sentinel) for
+            # free, padding, and non-final chunk lanes (whose sampled token
+            # is discarded)
+            dfa = np.zeros(lanes, np.int32)
+            for i, e in enumerate(entries):
+                req = e["req"]
+                if e["final"] and req.grammar is not None:
+                    dfa[i] = self._dfa_admission_state(req)
+                window = e["toks"][e["start"]:e["start"] + e["take"]]
+                ids[i, :len(window)] = window
+                lengths[i] = len(window)
+                prefix_lens[i] = e["start"]
+                tables[i] = self.cache.tables[e["slot"]]
+                if e["cow"] is not None:
+                    cow_src[i], cow_dst[i] = e["cow"]
+                if e["final"]:
+                    counts[i] = max(0, req.n_generated - 1)
+                s = req.sampling
+                seeds[i] = np.uint32(s.seed)
+                temps[i] = s.temperature
+                top_ks[i] = s.top_k
+                top_ps[i] = s.top_p
+            call = (self._state_arrays, jnp.asarray(ids),
+                    jnp.asarray(lengths), jnp.asarray(prefix_lens),
+                    jnp.asarray(tables), jnp.asarray(cow_src),
+                    jnp.asarray(cow_dst), jnp.asarray(counts),
+                    self.pool.k, self.pool.v,
+                    self.pool.k_scale, self.pool.v_scale,
+                    jnp.asarray(seeds), jnp.asarray(temps),
+                    jnp.asarray(top_ks), jnp.asarray(top_ps),
+                    *self._grammar_prefill_args(dfa))
 
         miss0 = self._prefill.misses
         t0 = time.perf_counter()
-        with _obs_span("serving.prefill_pass", cat="serving",
-                       engine=self._profiler_name,
-                       event_args={"batch_size": len(entries),
-                                   "lanes": lanes, "bucket": bucket}):
-            first, new_k, new_v, new_ks, new_vs = self._prefill(
-                self._state_arrays, jnp.asarray(ids),
-                jnp.asarray(lengths), jnp.asarray(prefix_lens),
-                jnp.asarray(tables), jnp.asarray(cow_src),
-                jnp.asarray(cow_dst), jnp.asarray(counts),
-                self.pool.k, self.pool.v,
-                self.pool.k_scale, self.pool.v_scale,
-                jnp.asarray(seeds), jnp.asarray(temps),
-                jnp.asarray(top_ks), jnp.asarray(top_ps),
-                *self._grammar_prefill_args(dfa))
-        self.pool.rebind(new_k, new_v, new_ks, new_vs)
-        first_np = np.asarray(first)     # the one prefill host sync
+        with _span("engine.prefill.enqueue", bucket=bucket, lanes=lanes,
+                   requests=len(entries)):
+            first, new_k, new_v, new_ks, new_vs = self._prefill(*call)
+            self.pool.rebind(new_k, new_v, new_ks, new_vs)
+        with _span("engine.prefill.wait"):
+            first_np = np.asarray(first)     # the one prefill host sync
         if self._prefill.misses == miss0:
             # measured per-token prefill throughput feeding the "auto"
             # swap-vs-recompute policy (compiling dispatches excluded:
@@ -2124,41 +2138,42 @@ class Engine:
         first_np, dfa = self._dispatch_prefill(entries,
                                                self._chunk_tokens, lanes)
         dt = time.perf_counter() - t0
-        name = self._profiler_name
-        self._chunk_dispatches += 1
-        self._chunk_count_total += len(entries)
-        if decode_live:
-            # decode lanes were live: this boundary's horizon was
-            # delayed by exactly this dispatch
-            self._prefill_interference_s += dt
-            _SRV_PREFILL_INTERFERE.inc(dt, engine=name)
-        for i, e in enumerate(entries):
-            req, lease, slot = e["req"], e["lease"], e["slot"]
-            prog = e["prog"]
-            cover = e["start"] + e["take"]
-            row = self.cache.tables[slot]
-            self.prefix.adopt(e["toks"][:cover], lease,
-                              block_of=lambda j, row=row: row[j])
-            prog.covered = cover
-            prog.chunks += 1
-            self._context_high_water = max(self._context_high_water,
-                                           cover)
-            _obs_events.instant("serving.prefill_chunk", cat="serving",
-                                slot=slot, request=req.request_id,
-                                chunk=prog.chunks, covered=cover,
-                                total=len(prog.toks))
-            if e["final"]:
-                del self._chunking[req.request_id]
-                _SRV_PREFILL_CHUNKS.observe(prog.chunks, engine=name)
-                if req.trace is not None:
-                    req.trace.add("prefill_chunked",
-                                  chunks=prog.chunks,
-                                  prefill_tokens=len(prog.toks))
-                self._finish_prefill_lane(req, slot, e["toks"],
-                                          int(first_np[i]), int(dfa[i]))
-            else:
-                self._pos[slot] = cover
-                self._state_dirty = True
+        with _span("engine.prefill.harvest"):
+            name = self._profiler_name
+            self._chunk_dispatches += 1
+            self._chunk_count_total += len(entries)
+            if decode_live:
+                # decode lanes were live: this boundary's horizon was
+                # delayed by exactly this dispatch
+                self._prefill_interference_s += dt
+                _SRV_PREFILL_INTERFERE.inc(dt, engine=name)
+            for i, e in enumerate(entries):
+                req, lease, slot = e["req"], e["lease"], e["slot"]
+                prog = e["prog"]
+                cover = e["start"] + e["take"]
+                row = self.cache.tables[slot]
+                self.prefix.adopt(e["toks"][:cover], lease,
+                                  block_of=lambda j, row=row: row[j])
+                prog.covered = cover
+                prog.chunks += 1
+                self._context_high_water = max(self._context_high_water,
+                                               cover)
+                _obs_events.instant("serving.prefill_chunk", cat="serving",
+                                    slot=slot, request=req.request_id,
+                                    chunk=prog.chunks, covered=cover,
+                                    total=len(prog.toks))
+                if e["final"]:
+                    del self._chunking[req.request_id]
+                    _SRV_PREFILL_CHUNKS.observe(prog.chunks, engine=name)
+                    if req.trace is not None:
+                        req.trace.add("prefill_chunked",
+                                      chunks=prog.chunks,
+                                      prefill_tokens=len(prog.toks))
+                    self._finish_prefill_lane(req, slot, e["toks"],
+                                              int(first_np[i]), int(dfa[i]))
+                else:
+                    self._pos[slot] = cover
+                    self._state_dirty = True
 
     def _retire(self, req):
         # release every table entry: private blocks return to the pool
@@ -2744,24 +2759,30 @@ class Engine:
         program re-compiles only on a new (h, nb, k) triple."""
         if k is None:
             k = self._resolve_spec_k()
-        self._ensure_blocks(h, k + 1)   # idempotent; step() already ran it
-        nb = self._attn_blocks(h, k + 1)
-        self._sync_device_state()
-        self._sync_tables(nb)
-        self._sync_grammar_tables()
+        with _span("engine.decode.prepare") as sp:
+            # idempotent; step() already ran it
+            self._ensure_blocks(h, k + 1)
+            nb = self._attn_blocks(h, k + 1)
+            sp.args.update(
+                state=bool(self._state_dirty),
+                tables=bool(self.cache.tables_dirty
+                            or nb != self._d_tables_nb))
+            self._sync_device_state()
+            self._sync_tables(nb)
+            self._sync_grammar_tables()
         seeds, temps, top_ks, top_ps, eos_ids, limits = self._d_params
-        misses0 = self._decode.misses
-        t_disp = time.perf_counter()
-        (tok, p, cnt, act, hb, nds), new_k, new_v, new_ks, new_vs, \
-            toks = self._decode(
-                self._state_arrays, self._d_tokens, self._d_pos,
-                self._d_counts, self._d_active, self._d_hist,
-                self._d_gates, seeds, temps, top_ks, top_ps, eos_ids,
-                limits, self._d_tables, self.pool.k, self.pool.v,
-                self.pool.k_scale, self.pool.v_scale, h, k,
-                self._d_dfa_state, self._d_dfa_next, self._d_dfa_mask,
-                self._d_dfa_forced)
-        self.pool.rebind(new_k, new_v, new_ks, new_vs)
+        with _span("engine.decode.enqueue", horizon=h, width=nb, k=k,
+                   lanes=int(np.count_nonzero(self._active))):
+            (tok, p, cnt, act, hb, nds), new_k, new_v, new_ks, new_vs, \
+                toks = self._decode(
+                    self._state_arrays, self._d_tokens, self._d_pos,
+                    self._d_counts, self._d_active, self._d_hist,
+                    self._d_gates, seeds, temps, top_ks, top_ps, eos_ids,
+                    limits, self._d_tables, self.pool.k, self.pool.v,
+                    self.pool.k_scale, self.pool.v_scale, h, k,
+                    self._d_dfa_state, self._d_dfa_next, self._d_dfa_mask,
+                    self._d_dfa_forced)
+            self.pool.rebind(new_k, new_v, new_ks, new_vs)
         self._d_tokens, self._d_pos = tok, p
         self._d_counts, self._d_active = cnt, act
         self._d_hist = hb
@@ -2776,20 +2797,13 @@ class Engine:
         step_bytes = self.cache.num_slots * nb * self.pool.bytes_per_block
         self._kv_bytes_read += step_bytes * h
         _SRV_KV_BYTES.inc(step_bytes * h, engine=self._profiler_name)
-        toks = np.asarray(toks)      # the ONE host sync per horizon
+        with _span("engine.decode.wait"):
+            toks = np.asarray(toks)      # the ONE host sync per horizon
         self._host_syncs += 1
-        dt_disp = time.perf_counter() - t_disp
         card = self._decode.last_card
         if card is not None:
             self._program_flops += card.flops or 0.0
             self._program_bytes += card.bytes_accessed or 0.0
-            # online roofline: this dispatch's bytes-accessed over its
-            # wall time vs the backend bandwidth — skipped on compiling
-            # dispatches, whose wall time is dominated by XLA
-            if self._decode.misses == misses0:
-                _obs_memory.publish_roofline(
-                    self._profiler_name, h, card.bytes_accessed,
-                    dt_disp, _obs_peaks.current_device_kind())
         return toks
 
     def step(self, horizon=None):
@@ -2799,7 +2813,18 @@ class Engine:
         the bucket; an explicit value is bucketed to a power of two
         (scanning past a request's retirement is correct — masked — just
         wasteful).  Returns the requests that finished during this
-        step."""
+        step.
+
+        The span log gets the step by phase: ``engine.admit`` (with the
+        ``engine.prefill.build`` / ``.enqueue`` / ``.wait`` / ``.harvest``
+        spans of each prefill dispatch inside it), then
+        ``engine.decode.prepare`` (twice: block coverage here, the
+        uploads in :meth:`_dispatch_horizon`) / ``.enqueue`` / ``.wait``
+        / ``.harvest`` (the walk and the step's counters) and
+        ``engine.step.publish`` (the gauges).  The device
+        certainly has work between an ``enqueue``'s start and its
+        ``wait``'s end; everywhere else the host alone decides whether
+        it does."""
         t0 = time.time()
         finished = []
         self._update_degradation()
@@ -2809,43 +2834,45 @@ class Engine:
         # chunk arms them, so the decode snapshot excludes them (their
         # masked -1 rows must never reach the harvest walk)
         if any(self._active[s] for s in self.scheduler.running):
-            h = self._resolve_horizon(horizon)
-            k = self._resolve_spec_k()
-            # block coverage (and any pressure preemption) BEFORE the
-            # harvest snapshot: a lane preempted here simply isn't in
-            # `active`, so its -1 harvest rows are never misread
-            self._ensure_blocks(h, k + 1)
+            with _span("engine.decode.prepare"):
+                h = self._resolve_horizon(horizon)
+                k = self._resolve_spec_k()
+                # block coverage (and any pressure preemption) BEFORE the
+                # harvest snapshot: a lane preempted here simply isn't in
+                # `active`, so its -1 harvest rows are never misread
+                self._ensure_blocks(h, k + 1)
         active = {s: r for s, r in self.scheduler.running.items()
                   if self._active[s]}
         if active:
             self._horizon_buckets.add(h)
-            with _obs_span("serving.decode_step", cat="serving",
-                           engine=self._profiler_name,
-                           event_args={"horizon": h, "spec_k": k}) as sp:
-                toks = self._dispatch_horizon(h, k)
+            toks = self._dispatch_horizon(h, k)
+            with _span("engine.decode.harvest") as sp:
                 harvested, wasted = self._harvest(toks, active, h, k,
                                                   finished)
-                sp.event_args["tokens_harvested"] = harvested
-            self._decode_steps += h
-            self._decode_horizons += 1
-            self._slot_busy_integral += h * len(active) / self.cache.num_slots
-            name = self._profiler_name
-            _SRV_DECODE_STEPS.inc(h, engine=name)
-            _SRV_HORIZON.observe(h, engine=name)
-            _SRV_TOKENS.inc(harvested, engine=name)
-            if wasted:
-                _SRV_WASTED.inc(wasted, engine=name)
-            # adaptive growth: stable horizon (nothing retired, nothing
-            # waiting) doubles the next one; churn resets to 1
-            if finished or self.scheduler.queue_depth:
-                self._grow = 1
-            else:
-                self._grow = min(max(1, int(self.config.max_horizon)),
-                                 max(self._grow, h) * 2)
+                sp.args.update(tokens=harvested, retired=len(finished))
+                self._decode_steps += h
+                self._decode_horizons += 1
+                self._slot_busy_integral += \
+                    h * len(active) / self.cache.num_slots
+                name = self._profiler_name
+                _SRV_DECODE_STEPS.inc(h, engine=name)
+                _SRV_HORIZON.observe(h, engine=name)
+                _SRV_TOKENS.inc(harvested, engine=name)
+                if wasted:
+                    _SRV_WASTED.inc(wasted, engine=name)
+                # adaptive growth: stable horizon (nothing retired,
+                # nothing waiting) doubles the next one; churn resets
+                # to 1
+                if finished or self.scheduler.queue_depth:
+                    self._grow = 1
+                else:
+                    self._grow = min(max(1, int(self.config.max_horizon)),
+                                     max(self._grow, h) * 2)
         dt = time.time() - t0
         self._busy_s += dt
-        _SRV_STEP.observe(dt, engine=self._profiler_name)
-        self._publish_gauges()
+        with _span("engine.step.publish"):
+            _SRV_STEP.observe(dt, engine=self._profiler_name)
+            self._publish_gauges()
         return finished
 
     def _harvest(self, toks, active, h, k_draft, finished):

@@ -62,6 +62,7 @@ from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 from ...observability import metrics as _obs_metrics
 from ...observability.server import PROM_CONTENT_TYPE
+from ...observability.span import complete as _span_complete
 from ..engine import Engine
 from ..faults import (_SRV_RETRIES, RetryPolicy, TransientSubmitError,
                       WorkerDeadError)
@@ -612,7 +613,12 @@ class Gateway:
         completion: one ``data:`` frame per harvested token chunk, a
         final frame carrying ``finish_reason``, then ``data: [DONE]``.
         Timeout aborts the request server-side and surfaces as
-        ``finish_reason: "abort"`` — the stream always terminates."""
+        ``finish_reason: "abort"`` — the stream always terminates.
+
+        The caller writes each frame before it asks for the next, so
+        the code after a ``yield`` runs when that write has returned:
+        there the ``gateway.deliver`` span of a token frame ends, which
+        began when the worker put the chunk on the handle's queue."""
         cmpl_id = self._cmpl_id()
         created = int(time.time())
         deadline = t_recv + self.config.request_timeout_s
@@ -633,6 +639,8 @@ class Gateway:
                     first = False
                 _GW_STREAM_TOKENS.inc(len(value))
                 yield _sse(self._chunk(cmpl_id, created, value))
+                _span_complete("gateway.deliver", value.t_put_ns,
+                               rid=handle.request_id, tokens=len(value))
             else:
                 yield _sse(self._chunk(cmpl_id, created, [],
                                        self._wire_reason(value)))

@@ -56,6 +56,7 @@ import threading
 import time
 
 from ...observability import events as _obs_events
+from ...observability.span import now_ns as _now_ns, span as _span
 from ..faults import (FAULT_STALL, SITE_WORKER_DISPATCH,
                       SITE_WORKER_SUBMIT, _SRV_FAILOVERS, _SRV_RETRIES,
                       DispatchFault, RetryPolicy, TransientSubmitError,
@@ -64,10 +65,19 @@ from ..scheduler import (FINISH_ABORT, FINISH_EOS, FINISH_LENGTH,
                          FINISHED)
 
 
+class TokenChunk(list):
+    """The token ids of one ``("tokens", chunk)`` event: a list that
+    carries the time it was put on the handle's queue (``t_put_ns``, the
+    span log's clock), so whoever writes the frame can close the
+    ``gateway.deliver`` span that began there."""
+
+    __slots__ = ("t_put_ns",)
+
+
 class StreamHandle:
     """The caller-side view of one request running on a worker thread.
 
-    ``events`` is a queue of ``("tokens", [ids])`` chunks — one per
+    ``events`` is a queue of ``("tokens", TokenChunk)`` chunks — one per
     decode horizon the request rode — terminated by exactly one
     ``("finish", finish_reason)``.  ``request`` is the live engine
     Request (its ``output_ids``/``finish_reason`` fill in as the worker
@@ -417,20 +427,26 @@ class EngineWorker:
                 raise WorkerCrash(f"worker {self.name} condemned")
             busy = self.engine.scheduler.has_work
             try:
-                cmd = (self._inbox.get_nowait() if busy
-                       else self._inbox.get(timeout=0.05))
+                if busy:
+                    cmd = self._inbox.get_nowait()
+                else:
+                    with _span("worker.idle"):
+                        cmd = self._inbox.get(timeout=0.05)
             except queue.Empty:
                 cmd = None
-            if cmd is not None and self._apply(cmd):
-                return
             # apply everything already queued before paying for a step
-            while True:
-                try:
-                    cmd = self._inbox.get_nowait()
-                except queue.Empty:
-                    break
-                if self._apply(cmd):
-                    return
+            if cmd is not None or not self._inbox.empty():
+                with _span("worker.inbox", commands=0) as sp:
+                    while True:
+                        if cmd is None:
+                            try:
+                                cmd = self._inbox.get_nowait()
+                            except queue.Empty:
+                                break
+                        sp.args["commands"] += 1
+                        if self._apply(cmd):
+                            return
+                        cmd = None
             if self.engine.scheduler.has_work:
                 try:
                     if self._faults is not None:
@@ -449,8 +465,10 @@ class EngineWorker:
                         # yield the GIL before the next dispatch so
                         # handler threads woken by the flush get to
                         # write their SSE frames now, not a
-                        # switch-interval (~5 ms) later
-                        time.sleep(0)
+                        # switch-interval (~5 ms) later; a span of its
+                        # own, so ``worker.flush`` is the flush alone
+                        with _span("worker.yield"):
+                            time.sleep(0)
             elif self._draining and not self._drained.is_set():
                 self.engine.drain()      # queue empty: releases blocks
                 self._drained.set()
@@ -560,19 +578,25 @@ class EngineWorker:
         terminal event) into its handle queue — the per-horizon flush
         the SSE stream rides.  Returns True if any event was pushed."""
         done, pushed = [], False
-        for rid, h in self._pending.items():
-            n = h.request.n_generated
-            if n > h.sent:
-                h.events.put(("tokens",
-                              list(h.request.output_ids[h.sent:n])))
-                h.sent = n
-                pushed = True
-            if h.request.status == FINISHED:
-                h.events.put(("finish", h.request.finish_reason))
-                done.append(rid)
-                pushed = True
-        for rid in done:
-            del self._pending[rid]
+        handles = tokens = 0
+        with _span("worker.flush") as sp:
+            for rid, h in self._pending.items():
+                n = h.request.n_generated
+                if n > h.sent:
+                    chunk = TokenChunk(h.request.output_ids[h.sent:n])
+                    chunk.t_put_ns = _now_ns()
+                    h.events.put(("tokens", chunk))
+                    handles += 1
+                    tokens += n - h.sent
+                    h.sent = n
+                    pushed = True
+                if h.request.status == FINISHED:
+                    h.events.put(("finish", h.request.finish_reason))
+                    done.append(rid)
+                    pushed = True
+            for rid in done:
+                del self._pending[rid]
+            sp.args.update(handles=handles, tokens=tokens)
         return pushed
 
 

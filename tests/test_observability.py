@@ -230,7 +230,7 @@ class TestEvents:
 
 
 class TestSpan:
-    def test_nesting_and_histogram(self):
+    def test_nesting_and_totals(self):
         reg_before = obs_metrics.value("span.seconds", name="outer-span")
         n_before = reg_before["count"] if reg_before else 0
         assert current_span() is None
@@ -244,10 +244,11 @@ class TestSpan:
         assert current_span() is None
         st = obs_metrics.value("span.seconds", name="outer-span")
         assert st["count"] == n_before + 1
-        # begin/end pairs landed on the timeline with depth recorded
-        begins = [e for e in obs_events.events(name="inner-span")
-                  if e.phase == obs_events.BEGIN]
-        assert begins and begins[-1].args["depth"] == d
+        # ONE record a span, written when it ends, with its cause
+        inner = obs_events.events(name="inner-span")
+        assert [e.phase for e in inner] == [obs_events.COMPLETE]
+        assert inner[-1].cause == "outer-span"
+        assert obs_events.events(name="outer-span")[-1].cause is None
 
     def test_elapsed_and_error_annotation(self):
         s = span("failing-span", cat="test")
@@ -255,18 +256,19 @@ class TestSpan:
             with s:
                 raise ValueError("x")
         assert s.elapsed is not None and s.elapsed >= 0
-        ends = [e for e in obs_events.events(name="failing-span")
-                if e.phase == obs_events.END]
-        assert ends[-1].args["error"] == "ValueError"
+        rec = obs_events.events(name="failing-span")[-1]
+        assert rec.args["error"] == "ValueError"
+        assert rec.dur_ns >= 0 and rec.start_ns > 0
 
-    def test_event_args_stay_off_histogram_labels(self):
-        with span("arg-span", cat="test", event_args={"path": "/tmp/x"}):
-            pass
+    def test_args_reach_the_record_only(self):
+        with span("arg-span", cat="test", path="/tmp/x") as sp:
+            sp.args["n"] = 3             # until the span ends
         st = obs_metrics.value("span.seconds", name="arg-span")
-        assert st["count"] >= 1            # labeled only by name
-        begins = [e for e in obs_events.events(name="arg-span")
-                  if e.phase == obs_events.BEGIN]
-        assert begins[-1].args["path"] == "/tmp/x"
+        assert st["count"] >= 1            # the family: by name only
+        assert obs_metrics.default_registry().get(
+            "span.seconds").label_sets().count((("name", "arg-span"),)) == 1
+        rec = obs_events.events(name="arg-span")[-1]
+        assert rec.args == {"path": "/tmp/x", "n": 3}
 
 
 class TestJitInstrumentation:
@@ -659,27 +661,28 @@ class TestExpositionConformance:
 
 
 class TestSpanErrorPath:
-    """Regression: the span histogram must be observed on the exception
-    path (with error=1), even if the event sink itself raises."""
+    """Regression: the span's totals must be kept on the exception path
+    too, even if the event ring itself raises."""
 
-    def test_error_observation_labeled(self):
-        st0 = obs_metrics.value("span.seconds", name="err-span",
-                                error="1")
+    def test_error_counted_under_the_name(self):
+        st0 = obs_metrics.value("span.seconds", name="err-span")
         n0 = st0["count"] if st0 else 0
         with pytest.raises(RuntimeError):
             with span("err-span", cat="test"):
                 raise RuntimeError("boom")
-        st = obs_metrics.value("span.seconds", name="err-span",
-                               error="1")
-        assert st["count"] == n0 + 1
-        # the success path stays on the unlabeled slot
         with span("err-span", cat="test"):
             pass
-        ok = obs_metrics.value("span.seconds", name="err-span")
-        assert ok["count"] >= 1
+        # two numbers a name, no label series beside it: the record says
+        # which of the two raised
+        assert obs_metrics.value("span.seconds",
+                                 name="err-span")["count"] == n0 + 2
+        assert obs_metrics.value("span.seconds", name="err-span",
+                                 error="1") is None
+        errs = [e.args.get("error")
+                for e in obs_events.events(name="err-span")[-2:]]
+        assert errs == ["RuntimeError", None]
 
-    def test_histogram_observed_even_if_event_sink_raises(self,
-                                                          monkeypatch):
+    def test_totals_kept_even_if_event_ring_raises(self, monkeypatch):
         import importlib
 
         span_mod = importlib.import_module(
@@ -692,12 +695,13 @@ class TestSpanErrorPath:
         n0 = st0["count"] if st0 else 0
         s = span_mod.span("sink-span", cat="test")
         s.__enter__()
-        monkeypatch.setattr(span_mod._events, "record", boom)
+        monkeypatch.setattr(obs_events.default_log(), "append", boom)
         with pytest.raises(RuntimeError):
             s.__exit__(None, None, None)
         st = obs_metrics.value("span.seconds", name="sink-span")
-        assert st["count"] == n0 + 1       # observed despite the raise
+        assert st["count"] == n0 + 1       # counted despite the raise
         assert s.elapsed is not None
+        assert current_span() is None
 
 
 class TestChromeTraceMetadata:
@@ -972,27 +976,18 @@ class TestMemoryLedger:
         led.mark_baseline()
         assert led.snapshot()["leak_delta_bytes"] == 0
 
-    def test_publish_roofline(self):
+    def test_backend_bandwidth_lookup(self):
         from paddle_tpu.observability import memory as mem
 
         v5e = "TPU v5 lite"                   # jax's device_kind
-        bw = mem.backend_bandwidth_gbs(v5e)
-        assert bw == 819.0                    # datasheet entry
+        assert mem.backend_bandwidth_gbs(v5e) == 819.0   # datasheet entry
         # a backend name is not a chip: no row, no default
         with pytest.raises(ValueError, match="no published peaks"):
             mem.backend_bandwidth_gbs("tpu")
-        # 819 GB in 2 s against an 819 GB/s roofline = 50%
-        util = mem.publish_roofline("e0", 8, 819.0e9, 2.0, v5e)
-        assert util == pytest.approx(0.5)
-        assert obs_metrics.value("memory.roofline_utilization",
-                                 engine="e0", horizon=8) == \
-            pytest.approx(0.5, abs=1e-4)
-        assert obs_metrics.value("memory.achieved_bandwidth_gbs",
-                                 engine="e0", horizon=8) == \
-            pytest.approx(409.5, rel=1e-3)
-        # degenerate dispatches publish nothing
-        assert mem.publish_roofline("e0", 8, 0, 1.0, v5e) is None
-        assert mem.publish_roofline("e0", 8, 100.0, 0.0, v5e) is None
+        # the per-dispatch roofline gauge is gone with its families
+        assert not hasattr(mem, "publish_roofline")
+        assert obs_metrics.default_registry().get(
+            "memory.roofline_utilization") is None
 
     def test_bandwidth_probe_memoized(self):
         from paddle_tpu.observability import memory as mem
