@@ -573,7 +573,8 @@ def kernel_phase(paged_widths, windows, norm_shape, gn_shape, tol,
 
     from paddle_tpu.ops.pallas import norms
     from paddle_tpu.serving.paged_attention import (
-        _pallas_paged_attention, _query_tile, _xla_paged_attention)
+        _pallas_paged_attention, _query_tile, _stream_blocks,
+        _xla_paged_attention)
 
     worst = {}
 
@@ -596,12 +597,15 @@ def kernel_phase(paged_widths, windows, norm_shape, gn_shape, tol,
                                        interpret=interpret))
     reference = jax.jit(_xla_paged_attention)
     rng = np.random.RandomState(SEED)
-    tiles = {}
+    qh, kh, d, block = paged_widths
+    geometry = {}
     for s in windows:
         case = _paged_case(rng, lanes, s, nb, paged_widths, False)
         record(f"paged_attention_s{s}", kernel(*case), reference(*case))
-        tiles[s] = _query_tile(s, paged_widths[0], paged_widths[2],
+        chunk = _stream_blocks(s, qh, block, kh, d, jnp.bfloat16,
                                jnp.bfloat16)
+        geometry[s] = (f"stream, {chunk} blocks a chunk" if chunk else
+                       f"tile, {_query_tile(s, qh, d, jnp.bfloat16)} rows")
     for s in windows[:2]:
         case = _paged_case(rng, lanes, s, nb, paged_widths, True)
         record(f"paged_attention_int8_s{s}", kernel(*case),
@@ -663,10 +667,12 @@ def kernel_phase(paged_widths, windows, norm_shape, gn_shape, tol,
         "compiled": not interpret, "checks": len(worst),
         "worst": max(worst.items(), key=lambda kv: kv[1]),
         "paged_widths_qh_kh_d_block": list(paged_widths),
-        "paged_query_tile_by_window": tiles,
+        "paged_geometry_by_window": geometry,
         "paged_route": "static: backend tpu -> kernel for every window; "
-                       "windows above the tile are cut into tiles, never "
-                       "sent to the scan",
+                       "a window whose working set fits the VMEM budget "
+                       "streams a lane's live blocks on an unquantized "
+                       "pool, the others and int8 pools are cut into "
+                       "tiles, none sent to the scan",
         "norm_shape": list(norm_shape),
         "norm_row_block": norms._row_block(n, h, jnp.bfloat16),
         "group_norm_shape": list(gn_shape),

@@ -2,9 +2,12 @@
 concat cache, continuous batching vs sequential generation, bucketed
 prefill compilation counters, sampling determinism."""
 
+import functools
+
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
@@ -1433,15 +1436,19 @@ class TestPagedAttentionVerify:
 class TestPallasMultiToken:
     """The generalized Pallas ragged kernel (interpret mode on CPU) vs
     the XLA fallback for every query window size s >= 1, on fp32 and
-    int8-quantized pools, including COW-aliased tables.  The kernel
-    runs the fallback's exact per-block recurrence, but the interpret
-    grid loop and the fallback's scan compile separately, so XLA:CPU
-    may reassociate the tiny per-block reductions — raw outputs match
-    to ~1 ulp (exact at most shapes), asserted here with a tight
-    tolerance; the BITWISE gate is stream equality of whole-engine runs
-    under ``PADDLE_TPU_PAGED_ATTN=pallas`` (see
-    ``test_tp2_chunked_prefill_pallas_kernel_parity``).  Kernel-vs-
-    kernel comparisons (same program, different tables) stay exact."""
+    int8-quantized pools, including COW-aliased tables.  The kernel's
+    tile geometry (int8 pools, windows too long to stream) runs the
+    fallback's exact per-block recurrence; its streaming geometry (every
+    fp case of these toy shapes) makes one softmax update a chunk of
+    blocks, the same sums in another order.  Either way the kernel and
+    the fallback's scan compile separately, so raw outputs match to the
+    last ulps, asserted here with a tight tolerance.  Whole-engine
+    stream equality under ``PADDLE_TPU_PAGED_ATTN=pallas`` is bitwise
+    where both sides run one geometry (see
+    ``test_tp2_chunked_prefill_pallas_kernel_parity``) and holds to a
+    near-tie where they mix two (``test_engine_mixed_geometries``).
+    Kernel-vs-kernel comparisons (same program, different tables) stay
+    exact."""
 
     ATOL = 1e-5
 
@@ -1459,6 +1466,127 @@ class TestPallasMultiToken:
         out = np.asarray(_pallas_paged_attention(q, k, v, tables, base,
                                                  interpret=True))
         np.testing.assert_allclose(out, ref, rtol=0, atol=self.ATOL)
+
+    # lanes of the serving cell's decode shape (KH=8, G=4, D=128, blocks
+    # of 16, bf16), by the deepest position each holds: both sides of a
+    # block edge, a lane one token long, a retired lane (position 0, a
+    # table of scratch entries only), and live-block counts on both
+    # sides of a multiple of the 8 blocks a streaming cell takes
+    REAL_WIDTH_LANES = {
+        "below_block_edge": (15, 127, 255, 16 * 37 - 1),
+        "at_block_edge": (16, 128, 256, 16 * 37),
+        "length_one_and_retired": (0, None, 1, 300),
+        "ragged_last_chunk": (16 * 7, 16 * 8, 16 * 9 - 1, 16 * 11 + 5),
+        "table_full": (0, 500, None, -1),
+    }
+
+    @pytest.mark.parametrize("nb", [64, 128])
+    @pytest.mark.parametrize("lanes", sorted(REAL_WIDTH_LANES))
+    def test_kernel_matches_fallback_real_widths(self, lanes, nb):
+        kh, g, d, bs = 8, 4, 128, 16
+        r = np.random.RandomState(7)
+        held = [nb * bs - 1 if p == -1 else p
+                for p in self.REAL_WIDTH_LANES[lanes]]
+        tables = np.zeros((len(held), nb), np.int32)
+        n_blocks = 1                                 # block 0 is scratch
+        for i, p in enumerate(held):
+            if p is not None:
+                live = p // bs + 1
+                tables[i, :live] = n_blocks + r.permutation(live)
+                n_blocks += live
+        pos = jnp.asarray([p or 0 for p in held], jnp.int32)
+        q = jnp.asarray(r.randn(len(held), 1, kh * g, d), jnp.bfloat16)
+        k = jnp.asarray(r.randn(n_blocks, bs, kh, d), jnp.bfloat16)
+        v = jnp.asarray(r.randn(n_blocks, bs, kh, d), jnp.bfloat16)
+        tables = jnp.asarray(tables)
+        ref = np.asarray(_xla_paged_attention(q, k, v, tables, pos),
+                         np.float32)
+        out = np.asarray(_pallas_paged_attention(q, k, v, tables, pos,
+                                                 interpret=True),
+                         np.float32)
+        # bf16 outputs of two programs: two ulps
+        np.testing.assert_allclose(out, ref, rtol=2.0 ** -7,
+                                   atol=2.0 ** -9)
+
+    def test_kernel_traced_once_a_program(self):
+        """Behind its module-level jit a stack of layers traces the
+        kernel once (``paged_attn.trace`` counts a trace); the unwrapped
+        function traces a layer, and the results are bitwise equal."""
+        from paddle_tpu.observability import metrics
+
+        q, k, v, tables, pos = self._case(b=3, nb=5, pos_vals=(9, 13, 2))
+        # whatever this process traced before, the count starts cold
+        _pallas_paged_attention.clear_cache()
+
+        def stack(attention, q):
+            for _ in range(4):
+                q = attention(q, k, v, tables, pos, interpret=True)
+            return q
+
+        def traces():
+            return metrics.value("paged_attn.trace", path="stream",
+                                 blocks_per_cell=8)
+
+        before = traces()
+        out = jax.jit(functools.partial(stack, _pallas_paged_attention))(q)
+        assert traces() - before == 1
+        unwrapped = jax.jit(functools.partial(
+            stack, _pallas_paged_attention.__wrapped__))(q)
+        assert traces() - before == 1 + 4
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(unwrapped))
+
+    def test_engine_mixed_geometries(self, monkeypatch):
+        """An engine whose prefill windows tile while its decode steps
+        stream.  At the toy widths everything fits the real VMEM budget
+        and streams, so the budget is cut to put the line between s=2
+        and the first prefill bucket, where the serving widths have it
+        between s=9 and their buckets.  Preemption then replays through
+        the tile geometry KV that decode wrote streaming: the
+        geometries differ in the output's last ulps, so this is no
+        bitwise guarantee, and the greedy streams of this toy model
+        still equal the scan-routed engine's, preempted or not."""
+        import sys
+
+        from paddle_tpu.observability import metrics
+
+        # the package exports the router under the module's own name
+        pa = sys.modules["paddle_tpu.serving.paged_attention"]
+
+        def traces(path, blocks):
+            return metrics.value("paged_attn.trace", path=path,
+                                 blocks_per_cell=blocks)
+
+        def run(preempt):
+            eng = Engine(m, cfg, register_profiler=False)
+            reqs = [eng.submit(p, s) for p, s in zip(prompts, samp)]
+            eng.step(horizon=2)                    # both lanes decoding
+            if preempt:
+                eng.preempt(reqs[1])
+            eng.run()
+            assert eng.counters()["preemptions"] == int(preempt)
+            return [r.output_ids for r in reqs]
+
+        m = _model(TINY_GQA)
+        cfg = EngineConfig(num_slots=2, max_seq_len=32, max_horizon=4,
+                           prefix_block_size=4, prefix_cache_bytes=0)
+        prompts = [[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], [9, 2, 6, 5, 3, 5, 8,
+                                                       9, 7]]
+        samp = [SamplingParams(max_new_tokens=10)] * 2
+        ref = run(preempt=False)                   # the scan, off TPU
+        monkeypatch.setenv("PADDLE_TPU_PAGED_ATTN", "pallas")
+        monkeypatch.setattr(pa, "VMEM_BUDGET_BYTES", 64 * 1024)
+        # the module-level jit keys on avals alone: drop what it traced
+        # under the real budget, and afterwards what it traces under this
+        _pallas_paged_attention.clear_cache()
+        before = traces("tile", 1), traces("stream", 8)
+        try:
+            assert run(preempt=False) == ref
+            assert run(preempt=True) == ref
+        finally:
+            _pallas_paged_attention.clear_cache()
+        assert traces("tile", 1) > before[0]       # the prefill buckets
+        assert traces("stream", 8) > before[1]     # the decode programs
 
     @pytest.mark.parametrize("w", [1, 4])
     def test_kernel_matches_fallback_quantized(self, w):

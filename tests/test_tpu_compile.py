@@ -62,28 +62,56 @@ def _has_kernel(compiled):
     return "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("s,pool_dtype", [(1, jnp.bfloat16),
-                                          (5, jnp.bfloat16),
-                                          (256, jnp.bfloat16),
-                                          (1024, jnp.bfloat16),
-                                          (1, jnp.int8)])
-def test_paged_attention(one_chip, s, pool_dtype):
-    """Decode, a verify window, two prefill buckets — and the int8 pool,
-    whose (1, 16) scale blocks the chip's tiling refused."""
+@pytest.mark.parametrize("heads,lanes,table,s,pool_dtype,geometry", [
+    ((QH, KH), LANES, TABLE_BLOCKS, 1, jnp.bfloat16, ("stream", 8)),
+    ((QH, KH), LANES, TABLE_BLOCKS, 5, jnp.bfloat16, ("stream", 8)),
+    ((QH, KH), LANES, TABLE_BLOCKS, 256, jnp.bfloat16, ("tile", 1)),
+    ((QH, KH), LANES, TABLE_BLOCKS, 1024, jnp.bfloat16, ("tile", 1)),
+    ((QH, KH), LANES, TABLE_BLOCKS, 1, jnp.int8, ("tile", 1)),
+    # the serving cell's decode programs
+    ((QH, KH), 32, 64, 1, jnp.bfloat16, ("stream", 8)),
+    ((QH, KH), 32, 128, 1, jnp.bfloat16, ("stream", 8)),
+    # the widest window that streams at these widths, and the next
+    ((QH, KH), LANES, TABLE_BLOCKS, 9, jnp.bfloat16, ("stream", 8)),
+    ((QH, KH), LANES, TABLE_BLOCKS, 16, jnp.bfloat16, ("tile", 1)),
+    # an MHA pool (the 7B presets: as many kv heads as query heads), where
+    # a chunk's columns are four times as many: decode streams half the
+    # blocks a chunk, the short prefill buckets tile
+    ((32, 32), LANES, TABLE_BLOCKS, 1, jnp.bfloat16, ("stream", 4)),
+    ((32, 32), LANES, TABLE_BLOCKS, 64, jnp.bfloat16, ("tile", 1)),
+    ((32, 32), LANES, TABLE_BLOCKS, 128, jnp.bfloat16, ("tile", 1)),
+    # a tp=4 shard's heads: few columns a chunk, so long windows stream
+    ((8, 2), LANES, TABLE_BLOCKS, 128, jnp.bfloat16, ("stream", 8))])
+def test_paged_attention(one_chip, heads, lanes, table, s, pool_dtype,
+                         geometry):
+    """Decode, verify windows, prefill buckets — and the int8 pool,
+    whose (1, 16) scale blocks the chip's tiling refused.  A window
+    whose working set fits streams a lane's live blocks (manual copies
+    out of HBM) on a bf16 pool; the others and the int8 pool take the
+    tile geometry.  Each case names the geometry it must get."""
+    from paddle_tpu.observability import metrics
     from paddle_tpu.serving.paged_attention import _pallas_paged_attention
 
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    pool = sds((POOL_BLOCKS, BLOCK, KH, D), pool_dtype)
-    args = [sds((LANES, s, QH, D), jnp.bfloat16), pool, pool,
-            sds((LANES, TABLE_BLOCKS), jnp.int32), sds((LANES,), jnp.int32)]
+    def traces():
+        path, blocks = geometry
+        return metrics.value("paged_attn.trace", path=path,
+                             blocks_per_cell=blocks)
+
+    qh, kh = heads
+    pool = sds((POOL_BLOCKS, BLOCK, kh, D), pool_dtype)
+    args = [sds((lanes, s, qh, D), jnp.bfloat16), pool, pool,
+            sds((lanes, table), jnp.int32), sds((lanes,), jnp.int32)]
     if pool_dtype == jnp.int8:
         scales = sds((POOL_BLOCKS, BLOCK), jnp.float32)
         args += [scales, scales]
+    before = traces()
     compiled = _compile(
         functools.partial(_pallas_paged_attention, interpret=False), *args)
     assert _has_kernel(compiled)
+    assert traces() == before + 1
 
 
 def test_flash_attention_fwd_bwd(one_chip):
