@@ -6,6 +6,7 @@ from . import gpt
 from . import bert
 from . import unet
 from . import llama
+from . import deepseek_v2
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, ERNIE_7B, LLAMA2_13B
 from .bert import BertConfig, BertModel, BertForMaskedLM
 from .unet import UNetConfig, UNet2DConditionModel
@@ -13,3 +14,4 @@ from .llama import (
     LlamaConfig, LlamaModel, LlamaForCausalLM,
     LLAMA2_7B, LLAMA3_8B,
 )
+from .deepseek_v2 import DeepSeekV2Config, DeepSeekV2Model, DeepSeekV2ForCausalLM

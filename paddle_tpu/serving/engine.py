@@ -115,7 +115,7 @@ from ..observability.span import build as _build, span as _span
 from .drafter import draft_tokens, forced_chain
 from .faults import (DEGRADE_LEVELS, FAULT_POOL_EXHAUSTED,
                      SITE_ENGINE_ADMIT, _SRV_DEGRADATION, _SRV_SHED)
-from .kv_cache import PagedKV, PagedKVCache
+from .kv_cache import PagedKV, PagedKVCache, model_cache_layout
 from .kv_host_tier import HostKVTier
 from .prefix_cache import PrefixCache
 from .sampling import (MASK_FLOOR, SamplingParams, request_key,
@@ -216,6 +216,17 @@ _SRV_GRAMMAR_MASKED = _obs_metrics.histogram(
 _SRV_KV_OCC = _obs_metrics.gauge(
     "serving.kv_pool_occupancy_ratio",
     "unified KV pool blocks in use / pool capacity")
+def _layer_stat_counters(model):
+    """One registry counter a column of what the model's layers return
+    with a step's harvest (``PagedKV.stats``), under the names the model
+    gives them (``model.layer_stat_names``, beside ``kv_cache_layout``:
+    an expert model names its routing counts); () for every other model.
+    The engine sums into them by layer and kind and owns no name."""
+    return tuple(_obs_metrics.counter(
+        n, "summed from the layers' PagedKV.stats, by layer and by kind "
+        "(prefill|decode)") for n in getattr(model, "layer_stat_names", ()))
+_KV_LATENT_LIVE = _obs_metrics.gauge(
+    "kv.latent_blocks_live", "blocks in use of a latent (MLA) pool")
 _SRV_BUCKETS = _obs_metrics.gauge(
     "serving.decode_bucket_count",
     "distinct compiled decode programs ((horizon, nb, K) triples)")
@@ -569,6 +580,14 @@ class EngineConfig:
     grammar_cache_keep: int = 8
 
 
+def _layer_stats(views):
+    """What the model's layers asked to have counted with this step's
+    harvest (``PagedKV.stats``), stacked [layers that set one, n]; None —
+    an empty pytree, the program unchanged — when no layer did."""
+    rows = [v.stats for v in views if v.stats is not None]
+    return jnp.stack(rows) if rows else None
+
+
 def _unpack_mask(rows, vocab):
     """Unpack packed legality-bitmask rows to a boolean mask.
 
@@ -644,23 +663,25 @@ class Engine:
         self._block_size = max(1, int(self.config.prefix_block_size) or 16)
         budget = (self.config.prefix_cache_bytes
                   if self.config.prefix_block_size else 0)
-        token_bytes = (mc.kv_heads * mc.head_dim
-                       * (1 if self._kv_quant
-                          else jnp.dtype(cache_dtype).itemsize)
-                       + (4 if self._kv_quant else 0))
-        bytes_per_block = (2 * len(model.model.layers) * self._block_size
-                           * token_bytes)
+        # the model states what a layer keeps of a token (a k/v pair, or a
+        # latent layer's one buffer): pool, radix store, host tier use it
+        layout = model_cache_layout(model)
+        token_shape = layout.token_shape
+        bytes_per_block = layout.block_bytes(
+            len(model.model.layers), self._block_size,
+            1 if self._kv_quant else jnp.dtype(cache_dtype).itemsize,
+            bool(self._kv_quant))
         prefix_capacity = int(budget) // bytes_per_block
         self.cache = PagedKVCache(
             num_layers=len(model.model.layers),
             num_slots=self.config.num_slots,
             max_seq_len=self.config.max_seq_len,
             block_size=self._block_size,
-            kv_heads=mc.kv_heads, head_dim=mc.head_dim,
+            kv_heads=token_shape[0], head_dim=token_shape[1],
             dtype=cache_dtype,
             num_blocks=int(self.config.kv_pool_blocks),
             extra_blocks=prefix_capacity,
-            quant_dtype=self._kv_quant)
+            quant_dtype=self._kv_quant, layout=layout)
         self.pool = self.cache.pool
         self.scheduler = Scheduler(self.config.num_slots,
                                    reorder_window=self.config.reorder_window)
@@ -672,7 +693,7 @@ class Engine:
         self.prefix = PrefixCache(
             num_layers=len(model.model.layers),
             block_size=self._block_size,
-            kv_heads=mc.kv_heads, head_dim=mc.head_dim,
+            kv_heads=token_shape[0], head_dim=token_shape[1],
             dtype=cache_dtype, budget_bytes=budget, pool=self.pool,
             bytes_per_block=self.pool.bytes_per_block)
         self._max_blocks = self.cache.max_blocks_per_slot
@@ -700,13 +721,16 @@ class Engine:
             self.host_tier = HostKVTier(
                 num_layers=len(model.model.layers),
                 block_size=self._block_size,
-                kv_heads=mc.kv_heads, head_dim=mc.head_dim,
+                kv_heads=token_shape[0], head_dim=token_shape[1],
                 store_dtype=np.dtype(jnp.dtype(self.pool.store_dtype)),
                 budget_bytes=host_budget,
                 bytes_per_block=self.pool.bytes_per_block,
-                quantized=bool(self._kv_quant))
+                quantized=bool(self._kv_quant), layout=layout)
             self.prefix.spill = self._demote_block
             self.prefix.spill_batch = self._demote_blocks
+        # what the model's layers have counted with a step's harvest
+        self._layer_counters = _layer_stat_counters(model)
+        self._stat_layers = 0            # layers that returned stats
         self._swap_ins = 0               # lane/prefix swap-in passes
         self._swap_outs = 0              # lane images saved at preempt
         self._swap_in_blocks = 0
@@ -1089,7 +1113,8 @@ class Engine:
         # COW first: duplicate-dst lanes (all no-COW lanes share dst 0)
         # write identical values, so the scatter is collision-safe
         pool_k = [pk.at[cow_dst].set(pk[cow_src]) for pk in pool_k]
-        pool_v = [pv.at[cow_dst].set(pv[cow_src]) for pv in pool_v]
+        pool_v = [pv if pv is None else pv.at[cow_dst].set(pv[cow_src])
+                  for pv in pool_v]       # None: a latent layer, no values
         if pool_ks is not None:
             pool_ks = [s.at[cow_dst].set(s[cow_src]) for s in pool_ks]
             pool_vs = [s.at[cow_dst].set(s[cow_src]) for s in pool_vs]
@@ -1111,7 +1136,7 @@ class Engine:
         return (first, [nv.k for nv in new_views],
                 [nv.v for nv in new_views],
                 [nv.k_scale for nv in new_views],
-                [nv.v_scale for nv in new_views])
+                [nv.v_scale for nv in new_views], _layer_stats(new_views))
 
     def _decode_fn(self, state_arrays, tokens, pos, counts, active, hist,
                    gates, seeds, temps, top_ks, top_ps, eos_ids, limits,
@@ -1255,15 +1280,16 @@ class Engine:
                      tuple(v.k for v in new_views),
                      tuple(v.v for v in new_views),
                      tuple(v.k_scale for v in new_views),
-                     tuple(v.v_scale for v in new_views)), harvest)
+                     tuple(v.v_scale for v in new_views)),
+                    (harvest, _layer_stats(new_views)))
 
         init = (tokens, pos, counts, active, hist, dfa_state,
                 tuple(pool_k), tuple(pool_v),
                 tuple(pool_ks), tuple(pool_vs))
-        (tok, p, cnt, act, hb, ds, pk, pv, pks, pvs), toks = jax.lax.scan(
-            body, init, None, length=horizon)
+        (tok, p, cnt, act, hb, ds, pk, pv, pks, pvs), (toks, stats) = \
+            jax.lax.scan(body, init, None, length=horizon)
         return ((tok, p, cnt, act, hb, ds), list(pk), list(pv),
-                list(pks), list(pvs), toks)
+                list(pks), list(pvs), toks, stats)
 
     # ------------------------------------------------------------ buckets
     def _bucket(self, prompt_len):
@@ -1995,10 +2021,13 @@ class Engine:
         t0 = time.perf_counter()
         with _span("engine.prefill.enqueue", bucket=bucket, lanes=lanes,
                    requests=len(entries)):
-            first, new_k, new_v, new_ks, new_vs = self._prefill(*call)
+            first, new_k, new_v, new_ks, new_vs, stats = \
+                self._prefill(*call)
             self.pool.rebind(new_k, new_v, new_ks, new_vs)
         with _span("engine.prefill.wait"):
             first_np = np.asarray(first)     # the one prefill host sync
+        if stats is not None:
+            self._count_layer_stats(np.asarray(stats)[None], "prefill")
         if self._prefill.misses == miss0:
             # measured per-token prefill throughput feeding the "auto"
             # swap-vs-recompute policy (compiling dispatches excluded:
@@ -2395,7 +2424,8 @@ class Engine:
         new_k, new_v, new_ks, new_vs = [], [], [], []
         for l in range(len(pool_k)):
             new_k.append(pool_k[l].at[ids].set(kd[:, l]))
-            new_v.append(pool_v[l].at[ids].set(vd[:, l]))
+            new_v.append(pool_v[l] if pool_v[l] is None
+                         else pool_v[l].at[ids].set(vd[:, l]))
             if ksd is not None:
                 new_ks.append(pool_ks[l].at[ids].set(ksd[:, l]))
                 new_vs.append(pool_vs[l].at[ids].set(vsd[:, l]))
@@ -2431,8 +2461,11 @@ class Engine:
         L = len(self.pool.k)
         k = np.stack([np.asarray(jax.device_get(self.pool.k[l][idx]))
                       for l in range(L)], axis=1)
-        v = np.stack([np.asarray(jax.device_get(self.pool.v[l][idx]))
-                      for l in range(L)], axis=1)
+        if self.pool.v[0] is None:       # latent pool: zero-width values
+            v = np.zeros(k.shape[:3] + (0,), k.dtype)
+        else:
+            v = np.stack([np.asarray(jax.device_get(self.pool.v[l][idx]))
+                          for l in range(L)], axis=1)
         if not self._kv_quant:
             return k, v, None, None
         ks = np.stack(
@@ -2652,7 +2685,7 @@ class Engine:
             tier.unpin_prefix(paths)
         n = len(plan)
         kd = np.empty((n,) + tier.k.shape[1:], tier.k.dtype)
-        vd = np.empty_like(kd)
+        vd = np.empty((n,) + tier.v.shape[1:], tier.v.dtype)
         ksd = vsd = None
         if tier.quantized:
             ksd = np.empty((n,) + tier.k_scale.shape[1:], np.float32)
@@ -2774,7 +2807,7 @@ class Engine:
         with _span("engine.decode.enqueue", horizon=h, width=nb, k=k,
                    lanes=int(np.count_nonzero(self._active))):
             (tok, p, cnt, act, hb, nds), new_k, new_v, new_ks, new_vs, \
-                toks = self._decode(
+                toks, stats = self._decode(
                     self._state_arrays, self._d_tokens, self._d_pos,
                     self._d_counts, self._d_active, self._d_hist,
                     self._d_gates, seeds, temps, top_ks, top_ps, eos_ids,
@@ -2800,6 +2833,8 @@ class Engine:
         with _span("engine.decode.wait"):
             toks = np.asarray(toks)      # the ONE host sync per horizon
         self._host_syncs += 1
+        if stats is not None:
+            self._count_layer_stats(np.asarray(stats), "decode")
         card = self._decode.last_card
         if card is not None:
             self._program_flops += card.flops or 0.0
@@ -2874,6 +2909,22 @@ class Engine:
             _SRV_STEP.observe(dt, engine=self._profiler_name)
             self._publish_gauges()
         return finished
+
+    def _count_layer_stats(self, stats, kind):
+        """What the model's layers returned with one dispatch's tokens
+        (already on the host's side of its one sync): ``stats`` [steps,
+        layers that set one, columns].  Column j goes to the counter the
+        model named j-th (``layer_stat_names``), summed over the steps,
+        by layer and by kind of dispatch; ``stats()["layer_stats"]`` reads
+        the same counters back (``_layer_stat_sums``): one record, and the
+        engine knows no column's meaning."""
+        name = self._profiler_name
+        per_layer = stats.sum(axis=0)
+        self._stat_layers = per_layer.shape[0]
+        for j, counter in enumerate(self._layer_counters):
+            for i, amount in enumerate(per_layer[:, j]):
+                counter.inc(int(amount), engine=name, layer=i,
+                            kind=kind)
 
     def _harvest(self, toks, active, h, k_draft, finished):
         """Walk the ``[h, num_slots, k_draft+1]`` harvested token
@@ -3076,6 +3127,8 @@ class Engine:
         _SRV_KV_BLOCKS.set(self.pool.blocks_in_use, engine=name)
         _SRV_KV_OCC.set(self.pool.blocks_in_use / self.pool.capacity,
                         engine=name)
+        if self.pool.layout.buffers == 1:
+            _KV_LATENT_LIVE.set(self.pool.blocks_in_use, engine=name)
         if self.host_tier is not None:
             _SRV_HOST_OCC.set(self.host_tier.occupancy, engine=name)
         _SRV_BUCKETS.set(len(self._decode_buckets), engine=name)
@@ -3342,7 +3395,11 @@ class Engine:
             "preemptions": self._preemptions,
             "dtype": str(jnp.dtype(self.pool.store_dtype)),
             "quant_dtype": self.pool.quant_dtype,
+            "buffers_per_layer": self.pool.layout.buffers,
         }
+        # what the model's layers had counted (PagedKV.stats), under the
+        # model's names: {name: {prefill|decode: sum over layers, steps}}
+        s["layer_stats"] = _layer_stat_sums(self)
         # tiered KV: the host spill arena under the pool.  Counters are
         # trace-exact per kind: kv_swap_out_bytes covers lane saves
         # (paired SWAP_OUT trace events), demote_bytes covers prefix
@@ -3446,3 +3503,13 @@ class Engine:
         if self.telemetry is not None:
             s["telemetry_port"] = self.telemetry.port
         return s
+
+
+def _layer_stat_sums(engine):
+    """{counter name: {prefill|decode: sum over layers}} of one engine,
+    read back from the registry counters ``_count_layer_stats`` feeds."""
+    name, n = engine._profiler_name, engine._stat_layers
+    return {c.name: {kind: int(sum(c.value(engine=name, layer=i, kind=kind)
+                                   for i in range(n)))
+                     for kind in ("prefill", "decode")}
+            for c in engine._layer_counters}

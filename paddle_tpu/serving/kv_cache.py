@@ -146,6 +146,47 @@ class SlottedKVCache:
 
 # --------------------------------------------------------------- paged
 
+@dataclass(frozen=True)
+class CacheLayout:
+    """What one layer keeps of a cached token, as the MODEL states it.
+
+    ``token_shape``: the trailing dims of a pool buffer
+    ``[num_blocks, block_size, *token_shape]``.  ``buffers``: how many such
+    buffers a layer has — 2 is the k/v pair every dense-attention model
+    keeps (``token_shape = (kv_heads, head_dim)``, the default built by
+    :func:`kv_pair_layout`); 1 is a LATENT layer (MLA), whose one buffer
+    holds keys whose leading columns are the values too, so no value
+    buffer exists (``pool.v`` is a list of ``None``).  The pool, the radix
+    store and the host tier take their bytes a block from here; a model
+    without a ``kv_cache_layout()`` method gets the k/v pair."""
+
+    token_shape: tuple
+    buffers: int = 2
+
+    @property
+    def token_elems(self):
+        return int(np.prod(self.token_shape))
+
+    def block_bytes(self, num_layers, block_size, itemsize, quantized=False):
+        """Device bytes of one block across every buffer and layer (a
+        quantized pool stores a 4-byte scale beside each token)."""
+        token = self.token_elems * itemsize + (4 if quantized else 0)
+        return self.buffers * num_layers * block_size * token
+
+
+def kv_pair_layout(kv_heads, head_dim):
+    return CacheLayout((int(kv_heads), int(head_dim)), 2)
+
+
+def model_cache_layout(model):
+    """The layout a model states (``model.kv_cache_layout()``), else the
+    k/v pair of its config's ``kv_heads`` x ``head_dim``."""
+    stated = getattr(model, "kv_cache_layout", None)
+    if stated is not None:
+        return stated()
+    return kv_pair_layout(model.config.kv_heads, model.config.head_dim)
+
+
 @dataclass
 class PagedKV:
     """One layer's paged-cache view for a batch of lanes.
@@ -163,6 +204,11 @@ class PagedKV:
              stores quantized blocks: the per-token dequantization step
              written beside each int8 token by ``paged_write_quant``;
              None on the fp path (attention then skips dequant).
+    v is None on a latent pool (``CacheLayout.buffers == 1``).
+    stats:   what a layer wants counted with the step's harvest, a small
+             int32 vector set on the view it RETURNS (an expert layer's
+             routing counts); None from every other layer, and then no
+             program changes (an empty pytree).
     """
 
     k: jax.Array
@@ -171,6 +217,7 @@ class PagedKV:
     pos: jax.Array
     k_scale: jax.Array = None
     v_scale: jax.Array = None
+    stats: jax.Array = None
 
     @property
     def block_size(self):
@@ -273,13 +320,21 @@ class PagedKVPool:
     COW, preemption — is unchanged: it moves block ids, not bytes."""
 
     def __init__(self, num_layers, num_blocks, block_size, kv_heads,
-                 head_dim, dtype=jnp.float32, quant_dtype=None):
+                 head_dim, dtype=jnp.float32, quant_dtype=None,
+                 layout=None):
         if num_blocks < 2:
             raise ValueError("paged pool needs >= 2 blocks (one scratch)")
         if quant_dtype not in (None, "int8"):
             raise ValueError(
                 f"unsupported KV quant_dtype {quant_dtype!r} "
                 "(supported: None, 'int8')")
+        self.layout = layout or kv_pair_layout(kv_heads, head_dim)
+        if quant_dtype and self.layout.buffers != 2:
+            raise ValueError(
+                "int8 KV is not supported on a latent pool: the per-token "
+                "scale is taken over a k/v head pair, and a latent row's "
+                "rotary key and compressed latent differ in range (use "
+                "kv_cache_dtype=None)")
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -289,9 +344,13 @@ class PagedKVPool:
         self.quant_dtype = quant_dtype
         store_dtype = jnp.int8 if quant_dtype else dtype
         self.store_dtype = store_dtype
-        shape = (num_blocks, block_size, kv_heads, head_dim)
+        shape = (num_blocks, block_size) + tuple(self.layout.token_shape)
         self.k = [jnp.zeros(shape, store_dtype) for _ in range(num_layers)]
-        self.v = [jnp.zeros(shape, store_dtype) for _ in range(num_layers)]
+        # a latent layer keeps no value buffer: None is an empty pytree,
+        # so the engine's programs thread it like the fp path's scales
+        self.v = [jnp.zeros(shape, store_dtype)
+                  if self.layout.buffers == 2 else None
+                  for _ in range(num_layers)]
         if quant_dtype:
             # zero scales dequantize the zero-initialized blocks to the
             # exact 0.0 the fp pool starts with
@@ -326,11 +385,9 @@ class PagedKVPool:
         f32 scale stored beside each token — the figure the engine's
         ``serving.kv_bytes_read`` accounting multiplies, so quant bench
         numbers come from real bytes, not an fp-equivalent estimate."""
-        token_bytes = (self.kv_heads * self.head_dim
-                       * jnp.dtype(self.store_dtype).itemsize)
-        if self.quant_dtype:
-            token_bytes += jnp.dtype(jnp.float32).itemsize
-        return 2 * self.num_layers * self.block_size * token_bytes
+        return self.layout.block_bytes(
+            self.num_layers, self.block_size,
+            jnp.dtype(self.store_dtype).itemsize, bool(self.quant_dtype))
 
     def alloc(self):
         """Claim a free block (refcount 1), or None when exhausted."""
@@ -385,7 +442,7 @@ class PagedKVCache:
 
     def __init__(self, num_layers, num_slots, max_seq_len, block_size,
                  kv_heads, head_dim, dtype=jnp.float32, num_blocks=0,
-                 extra_blocks=0, quant_dtype=None):
+                 extra_blocks=0, quant_dtype=None, layout=None):
         self.num_layers = num_layers
         self.num_slots = num_slots
         self.max_seq_len = max_seq_len
@@ -401,7 +458,7 @@ class PagedKVCache:
                           + extra_blocks)
         self.pool = PagedKVPool(num_layers, num_blocks, block_size,
                                 kv_heads, head_dim, dtype,
-                                quant_dtype=quant_dtype)
+                                quant_dtype=quant_dtype, layout=layout)
         self.tables = np.zeros((num_slots, self.max_blocks_per_slot),
                                np.int32)
         self.tables_dirty = True

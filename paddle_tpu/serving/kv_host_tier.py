@@ -107,7 +107,7 @@ class HostKVTier:
 
     def __init__(self, num_layers, block_size, kv_heads, head_dim,
                  store_dtype, budget_bytes, bytes_per_block,
-                 quantized=False):
+                 quantized=False, layout=None):
         self.num_layers = int(num_layers)
         self.block_size = int(block_size)
         self.kv_heads = int(kv_heads)
@@ -117,10 +117,16 @@ class HostKVTier:
         self.bytes_per_block = int(bytes_per_block)
         self.budget_bytes = int(budget_bytes)
         self.capacity = max(0, self.budget_bytes // self.bytes_per_block)
-        shape = (self.capacity, self.num_layers, self.block_size,
-                 self.kv_heads, self.head_dim)
-        self.k = np.zeros(shape, self.store_dtype)
-        self.v = np.zeros(shape, self.store_dtype)
+        # the model's stated layout (kv_cache.CacheLayout) when given: a
+        # latent layer keeps one buffer, and its value arena is zero-width
+        # so that every block still moves as a (k, v) pair of arrays
+        token_shape = (tuple(layout.token_shape) if layout is not None
+                       else (self.kv_heads, self.head_dim))
+        shape = (self.capacity, self.num_layers, self.block_size)
+        self.k = np.zeros(shape + token_shape, self.store_dtype)
+        self.v = np.zeros(
+            shape + (token_shape if layout is None or layout.buffers == 2
+                     else (0,)), self.store_dtype)
         if self.quantized:
             sshape = (self.capacity, self.num_layers, self.block_size)
             self.k_scale = np.zeros(sshape, np.float32)

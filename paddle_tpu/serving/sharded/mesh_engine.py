@@ -62,7 +62,8 @@ from ...nn import functional as F
 from ...ops.rope import apply_rotary_emb
 from ...tensor import manipulation as M
 from ..engine import Engine
-from ..kv_cache import PagedKV, paged_write, paged_write_quant
+from ..kv_cache import (PagedKV, model_cache_layout, paged_write,
+                        paged_write_quant)
 from ..paged_attention import paged_attention
 from .layout import ServingSpecLayout
 
@@ -85,6 +86,15 @@ class MeshEngine(Engine):
     def __init__(self, model, config=None, mesh_shape=None, tp=None,
                  register_profiler=True, layout=None):
         self.mesh_shape = self._norm_mesh_knob(mesh_shape, tp)
+        if model_cache_layout(model).buffers != 2:
+            raise ValueError(
+                f"MeshEngine cannot serve {type(model).__name__}: it splits "
+                "the cache's k/v pair by kv head, and this model states one "
+                "buffer a layer (a latent, MLA, cache: one head that every "
+                "query head reads); its shard forward is a hand copy of the "
+                "dense GQA layer and knows no expert layers either. Serve "
+                "this family on one chip through Engine (create_llm_engine "
+                "without tp/mesh_shape)")
         dp, tp_size = self.mesh_shape
         self.tp = tp_size
         self.layout = layout or ServingSpecLayout()
