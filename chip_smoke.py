@@ -57,8 +57,8 @@ SERVE_SLOTS, SERVE_MAX_SEQ = 8, 2048
 SERVE_POOL_BLOCKS = 2048
 #: one below 128, one of 256-512 (and a second in its bucket, so that one
 #: prefill dispatch carries two lanes), one of 1024 or more.  Each compiled
-#: serving program costs ~19 s of XLA time whatever the depth — the
-#: full-vocabulary sort of the sampler — so every extra bucket is felt.
+#: serving program costs XLA seconds whatever the depth (7-12 in the
+#: benchmark's serve cells), so every extra bucket is felt.
 SERVE_PROMPT_LENS = (40, 300, 420, 1100)
 SERVE_NEW_TOKENS = 32
 #: paged cache + Pallas kernel against the dense model(ids) forward with

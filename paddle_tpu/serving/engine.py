@@ -118,8 +118,8 @@ from .faults import (DEGRADE_LEVELS, FAULT_POOL_EXHAUSTED,
 from .kv_cache import PagedKV, PagedKVCache, model_cache_layout
 from .kv_host_tier import HostKVTier
 from .prefix_cache import PrefixCache
-from .sampling import (MASK_FLOOR, SamplingParams, request_key,
-                       sample_token, sample_window)
+from .sampling import (MASK_FLOOR, SamplingParams, sample_batch,
+                       sample_window)
 from .scheduler import Scheduler
 from .structured.grammar import (GrammarSlab, as_grammar_spec,
                                  compile_grammar)
@@ -225,6 +225,10 @@ def _layer_stat_counters(model):
     return tuple(_obs_metrics.counter(
         n, "summed from the layers' PagedKV.stats, by layer and by kind "
         "(prefill|decode)") for n in getattr(model, "layer_stat_names", ()))
+_SAMPLER_DISPATCH = _obs_metrics.counter(
+    "sampler.dispatch",
+    "decode and verify dispatches, by the filters whose search the "
+    "sampler runs in them (filters=none|top_p|top_k|top_k+top_p)")
 _KV_LATENT_LIVE = _obs_metrics.gauge(
     "kv.latent_blocks_live", "blocks in use of a latent (MLA) pool")
 _SRV_BUCKETS = _obs_metrics.gauge(
@@ -1131,8 +1135,7 @@ class Engine:
         if dfa_mask is not None:
             allowed = _unpack_mask(dfa_mask[dfa_state], last.shape[-1])
             last = jnp.where(allowed, last, MASK_FLOOR)
-        keys = jax.vmap(request_key)(seeds, counts)
-        first = jax.vmap(sample_token)(last, keys, temps, top_ks, top_ps)
+        first = sample_batch(last, seeds, counts, temps, top_ks, top_ps)
         return (first, [nv.k for nv in new_views],
                 [nv.v for nv in new_views],
                 [nv.k_scale for nv in new_views],
@@ -2830,6 +2833,13 @@ class Engine:
         step_bytes = self.cache.num_slots * nb * self.pool.bytes_per_block
         self._kv_bytes_read += step_bytes * h
         _SRV_KV_BYTES.inc(step_bytes * h, engine=self._profiler_name)
+        # the sampler's batch predicates (``sampling._draw_rows``), from
+        # the host copies of the arrays the program reads them from
+        sampling = self._temps > 0
+        _SAMPLER_DISPATCH.inc(filters="+".join(
+            f for f, asks in (("top_k", self._top_ks > 0),
+                              ("top_p", self._top_ps < 1.0))
+            if (sampling & asks).any()) or "none")
         with _span("engine.decode.wait"):
             toks = np.asarray(toks)      # the ONE host sync per horizon
         self._host_syncs += 1

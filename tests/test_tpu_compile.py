@@ -199,3 +199,21 @@ def test_flash_attention_in_a_sharded_step(topo):
     assert "all-gather" not in text and "all-to-all" not in text
     assert all(s.spec == P("dp", None, "mp", None)
                for s in compiled.output_shardings)
+
+
+@pytest.mark.parametrize("lanes,vocab", [(128, 102400), (32, 32768)])
+def test_sampler_at_the_serve_cells_shapes(one_chip, lanes, vocab):
+    """The two serve cells' sampler, compiled whole: the chip's compiler
+    finds no sort in it (the full-vocabulary sort was 7-10 s of every
+    serving program's compile) and both threshold searches as loops."""
+    from paddle_tpu.serving.sampling import sample_batch
+
+    row = lambda dtype: jax.ShapeDtypeStruct((lanes,), dtype,
+                                             sharding=one_chip)
+    logits = jax.ShapeDtypeStruct((lanes, vocab), jnp.bfloat16,
+                                  sharding=one_chip)
+    text = _compile(sample_batch, logits, row(jnp.uint32), row(jnp.int32),
+                    row(jnp.float32), row(jnp.int32),
+                    row(jnp.float32)).as_text()
+    assert " sort(" not in text
+    assert text.count(" while(") >= 2
