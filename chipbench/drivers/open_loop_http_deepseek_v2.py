@@ -9,7 +9,7 @@ window (and of its traced part) for the readers, and `calibrate` for
 chipbench/control.py.
 
 And a LEAD-IN, which the dense driver lacks.  A request of this mix lives
-longer (about 280 tokens at 90-100 ms) than a window of 30 s, so a window
+for a good part of a window (about 280 tokens at 55-100 ms), so a window
 that starts on an empty engine is a ramp and measures how early a seed's
 first arrivals fall.  Here the schedule is one trace over `lead_in_s` +
 the window's seconds; the client is told to go during set-up, the lead-in
@@ -42,18 +42,12 @@ def build_engine(ctx, warm=True):
     import jax.numpy as jnp
 
     from paddle_tpu.inference import create_llm_engine
-    from paddle_tpu.observability import events
     from paddle_tpu.serving.gateway import Gateway, GatewayConfig
 
     from chipbench import program_deepseek_v2 as program
     from chipbench import weights_deepseek_v2 as W
 
     cfg, mix = ctx.cfg, ctx.traffic
-    # the program's span ring holds 65,536 records by default; 128 lanes
-    # streaming through the window and for a minute after the close write
-    # more than that, and the traced part (the window's first seconds),
-    # which the span readers need, would be gone before it is read
-    events.set_capacity(1 << 20)
     st = serving.State()
     t = time.perf_counter()
     dtype = jnp.dtype(cfg["dtype"])
@@ -135,14 +129,11 @@ def _moe_delta(a, b):
             "decode_steps": b["decode_steps"] - a["decode_steps"]}
 
 
-def _queue(stats):
-    return {k: stats[k] for k in ("queue_depth", "active_slots")}
-
-
 def drain(ctx, st):
     """serving.drain (times come back counted from the window's "go", so
-    the lead-in's are negative), then each request's tokens put where they
-    were streamed, and the routing and the queue at the window's ends."""
+    the lead-in's are negative; it keeps the queue at the window's ends),
+    then each request's tokens put where they were streamed, and the
+    routing between the window's ends."""
     serving.drain(ctx, st)
     r = ctx.records
     close = r["seconds"]
@@ -153,12 +144,8 @@ def drain(ctx, st):
     traced = getattr(st, "stats_at_trace_end", None)
     r["moe_traced"] = (_moe_delta(st.stats_at_go, traced)
                        if traced is not None else r["moe"])
-    r["queue"] = {"at_go": _queue(st.stats_at_go),
-                  "at_close": _queue(st.stats_at_close)}
-    ends = [rec["end_s"] for rec in r["requests"] if rec["end_s"] is not None]
-    ctx.log(f"queue and live lanes {r['queue']}; last answer "
-            f"{max(ends) - close:.1f} s after the close; routing in the "
-            f"window {r['moe']}, in its traced part {r['moe_traced']}")
+    ctx.log(f"routing in the window {r['moe']}, in its traced part "
+            f"{r['moe_traced']}")
 
 
 # ------------------------------------------------------------------- check

@@ -10,13 +10,21 @@ time only) with these changes:
   choices, which requests are greedy) are drawn from the mix's own
   `shape_seed`, so every `--seed` offers the same set of sizes and gaps;
   together they are one fixed trace.  `--seed` decides where in that trace
-  the window STARTS (a rotation) and the token ids.  A run's work, and
-  which long prompt meets which short gap, then do not depend on the seed;
-  a tail read on two seeds differs by timing, not by luck of the draw.
-  (Tried first, PR 23: a fresh permutation a seed spread ttft_p95_ms by
-  about 7 % over three seeds.)  An open loop sends
-  exactly round(rate x seconds) requests, the gaps scaled so that all are
-  due inside the window;
+  the window STARTS (a rotation) and the token ids.  Which long prompt
+  meets which short gap then does not depend on the seed; a tail read on
+  two seeds differs by timing, not by luck of the draw.  (Tried first,
+  PR 23: a fresh permutation a seed spread ttft_p95_ms by about 7 % over
+  three seeds.)  An open loop sends exactly round(rate x seconds)
+  requests, the gaps scaled so that all are due inside the window;
+* a rotation still changes the WORK of a window that closes on a queue (an
+  overload cell finishes only the first three quarters of what it is sent,
+  and where the trace starts decides which three quarters: their prompts
+  are prefill time, their budgets how often a lane turns over).  A mix
+  that states `order_block` gets no rotation: every seed sends the trace's
+  arrivals at the trace's own times, and the seed only shuffles the sizes
+  among each `order_block` consecutive arrivals (and draws the token ids).
+  Whatever prefix of the trace a window gets through is then the same set
+  of requests for every seed, to within one block;
 * lengths are clipped to the mix's closed range, so the set of compiled
   programs is bounded;
 * sampling parameters are part of the request (the original's replay always
@@ -33,6 +41,8 @@ Mix parameters (all under the traffic file's top level):
   sampling  {"temperature", "top_p", "top_k"}    of the sampled requests
   greedy_fraction   share of requests sent at temperature 0
   shape_seed        seed of the sizes
+  order_block       the seed shuffles sizes inside blocks of this many
+                    consecutive arrivals and rotates nothing (optional)
 """
 
 from __future__ import annotations
@@ -125,6 +135,16 @@ def schedule(mix, seed, seconds, vocab, model_id="paddle-tpu"):
     # where in it the window starts (and the token ids), so every seed
     # meets the same coincidences of long prompts and short gaps
     order = (np.arange(n) + int(rng.integers(0, n))) % n
+    when = order
+    block = int(mix.get("order_block", 0))
+    if block:
+        # no rotation: the trace's arrivals at their own times, the sizes
+        # shuffled inside each block of consecutive arrivals, so that any
+        # prefix of the trace is the same work whatever the seed
+        when = np.arange(n)
+        block_rng = np.random.default_rng([int(seed), 23])
+        order = np.concatenate([lo + block_rng.permutation(min(block, n - lo))
+                                for lo in range(0, n, block)])
     pre = mix.get("prefixes") or {}
     prefix_rng = np.random.default_rng([int(seed), 13])
     prefixes = [[int(t) for t in prefix_rng.integers(0, vocab, pre["len"])]
@@ -133,7 +153,7 @@ def schedule(mix, seed, seconds, vocab, model_id="paddle-tpu"):
     requests, t = [], 0.0
     for i in range(n):
         length, budget, pop = rows[order[i]]
-        t += gaps[order[i]]
+        t += gaps[when[i]]
         head = prefixes[pop] if pop >= 0 else []
         ids = head + [int(x) for x in rng.integers(
             0, vocab, length - len(head))]

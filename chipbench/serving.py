@@ -167,8 +167,15 @@ def build_engine(ctx, warm=True):
 def start_client(ctx, st, mix, seconds):
     """The schedule from the seed, written out, and the client process
     started and waiting for "go"."""
+    from paddle_tpu.observability import events
+
     from chipbench import loadgen
 
+    # the program's span ring holds 65,536 records by default; full lanes
+    # streaming through the window and until the last answer write more
+    # than that, and the traced part (the window's first seconds), which
+    # the span readers need, would be gone before it is read
+    events.set_capacity(1 << 20)
     st.sched = loadgen.schedule(mix, ctx.seed, seconds,
                                 ctx.cfg["vocab_size"])
     os.makedirs(ctx.work_dir, exist_ok=True)
@@ -259,9 +266,15 @@ def drain(ctx, st):
     ctx.log(f"programs: plan {st.programs['plan']}, after warm-up {warmed}, "
             f"after the window {after['count']}")
     r["queue_s"] = queue_waits(st.engine)
+    # requests waiting for a lane and lanes live at the window's two ends:
+    # above its knee a cell closes on a queue, under it on none
+    r["queue"] = {end: {k: stats[k] for k in ("queue_depth", "active_slots")}
+                  for end, stats in (("at_go", a), ("at_close", b))}
     n_ok = sum(1 for rec in recs if rec["ok"])
+    ends = [rec["end_s"] for rec in recs if rec["end_s"] is not None]
     ctx.log(f"{len(recs)} requests sent, {n_ok} complete; engine in window "
-            f"{r['engine']}")
+            f"{r['engine']}; queue and live lanes {r['queue']}; last answer "
+            f"{max(ends, default=close) - close:.1f} s after the close")
 
 
 def queue_waits(engine):

@@ -1,7 +1,11 @@
 #!/usr/bin/env python3
-"""The sweep that fixes an open-loop mix's rate: one engine, warmed once,
-then a window at each of a few rates, on the chip.  The highest rate that
-leaves no growing backlog is the knee; the cell runs at four fifths of it.
+"""The sweep that reads an open-loop mix's capacity: one engine, warmed
+once, then a window at each of a few rates, on the chip.  From some rate on
+the queue at the close grows and the tokens/s stay flat: that flat reading
+is the capacity, and the rate rule of chipbench/README.md fixes the cell's
+rate from it (an overload cell offers 1.4 times the requests/s the engine
+completes with its lanes full: the flat tokens/s over the mean generated
+tokens a request of the mix's fixed trace).
 
     python3 chipbench/sweep.py --workload <name> --rates 4,6,8,10,12 --seconds 20 --seed 7
 """

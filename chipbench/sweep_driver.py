@@ -7,8 +7,9 @@ offered for the mix's `lead_in_s` before its window, every later one for
 `--settle` seconds, so that each window of `--seconds` begins in the state
 its rate leaves the engine in, and not on an empty one.  A window's row
 gives the queue and the live lanes at its two ends and the tokens the
-engine generated in between; the highest rate whose queue did not grow is
-the knee.  Nothing is waited for after the last window.
+engine generated in between; the tokens/s of the rates whose queue grows
+are the capacity the rate rule of chipbench/README.md starts from.  Nothing
+is waited for after the last window.
 
     python3 chipbench/sweep_driver.py --workload <name> --rates 3.5,4,4.5,5 --seconds 20 --seed 7
 """
