@@ -585,13 +585,13 @@ def kernel_phase(paged_widths, windows, norm_shape, gn_shape, tol,
         worst[name] = float(np.abs(got - want).max()) / scale
 
     # every Pallas kernel of the training path, forward and backward,
-    # against its XLA twin: bench.py's own gate, as it is (it picks
+    # against its XLA twin: kernel_checks.py's gate, as it is (it picks
     # interpret mode from the backend itself)
-    import bench
+    import kernel_checks
 
-    for name, err, limit in bench._kernel_checks():
+    for name, err, limit in kernel_checks._kernel_checks():
         # each check has its own limit; scale so all share `tol`
-        worst[f"bench.{name}"] = float(err) / limit * tol
+        worst[f"kernel_checks.{name}"] = float(err) / limit * tol
 
     kernel = jax.jit(functools.partial(_pallas_paged_attention,
                                        interpret=interpret))

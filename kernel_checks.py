@@ -1,16 +1,10 @@
-#!/usr/bin/env python
-"""Benchmark: flagship GPT (ERNIE/LLaMA-style) jitted train step on one chip.
+"""Numerics checks of every Pallas kernel path, forward and backward,
+against its XLA twin, on the current backend.
 
-Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"}.
-value = tokens/sec/chip; vs_baseline = achieved MFU / 0.50 (a derived
-A100-parity anchor — no published reference numbers exist).  Runs on a
-TPU only: without one it exits non-zero and prints no result.
+``chip_smoke.py`` runs them on the chip (real Mosaic) before anything else
+is trusted there; ``tests/test_kernel_smoke_gate.py`` runs them interpreted
+on the CPU and proves that the gate can fail.  No timing is taken here.
 """
-
-import json
-import os
-import sys
-import time
 
 
 def _kernel_checks(perturb=None):
@@ -181,77 +175,3 @@ def kernel_smoke(perturb=None):
     (SURVEY.md §4 tolerance discipline; VERDICT r2 item 3)."""
     for name, err, tol in _kernel_checks(perturb):
         assert err < tol, f"{name} kernel mismatch: {err} >= {tol}"
-
-
-def main():
-    import jax
-
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        sys.exit(f"bench.py measures the chip; found {dev.platform!r} "
-                 f"({dev.device_kind}) and no TPU")
-    import numpy as np
-
-    import paddle_tpu as paddle
-    from paddle_tpu.models import GPTConfig, GPTForCausalLM
-    from paddle_tpu.observability.peaks import chip_peaks
-    from paddle_tpu.utils import compile_cache
-
-    compile_cache.enable()
-    kernel_smoke()  # numerics gate before timing
-    # ~0.5B-param config: big enough for meaningful MFU, fits 16G HBM;
-    # fused chunked LM-head CE keeps the [B*S, 32k] f32 logits out of HBM
-    cfg = GPTConfig(vocab_size=32000, hidden_size=1536, intermediate_size=4096,
-                    num_hidden_layers=12, num_attention_heads=12,
-                    max_position_embeddings=2048, fused_lm_loss=True)
-    batch, seq, steps, windows = 16, 1024, 10, 3
-    batch = int(os.environ.get("BENCH_BATCH", batch))
-
-    paddle.seed(0)
-    model = GPTForCausalLM(cfg)
-    model.to(dtype="bfloat16")  # bf16 params + activations on the MXU
-    n_params = sum(p.size for p in model.parameters())
-
-    opt = paddle.optimizer.AdamW(learning_rate=1e-4, parameters=model.parameters(),
-                                 multi_precision=True)
-
-    def loss_fn(net, ids, labels):
-        loss, _ = net(ids, labels=labels)
-        return loss
-
-    step = paddle.jit.TrainStep(model, loss_fn, opt)
-
-    rng = np.random.RandomState(0)
-    ids = paddle.to_tensor(rng.randint(0, cfg.vocab_size, (batch, seq)).astype(np.int32))
-
-    # compile + warmup
-    step(ids, ids)
-    step(ids, ids)
-    import jax.numpy as jnp
-
-    jnp.zeros(()).block_until_ready()
-
-    best_dt = None
-    for _ in range(windows):
-        t0 = time.time()
-        for _ in range(steps):
-            loss = step(ids, ids)
-        float(loss)  # sync
-        dt = time.time() - t0
-        best_dt = dt if best_dt is None else min(best_dt, dt)
-
-    tokens_per_sec = batch * seq * steps / best_dt
-    # 6*N FLOPs/token (fwd+bwd); attention FLOPs excluded (conservative)
-    flops_per_tok = 6 * n_params
-    mfu = tokens_per_sec * flops_per_tok / chip_peaks(dev.device_kind).bf16_flops
-    print(json.dumps({
-        "metric": f"tokens/sec/chip GPT-{n_params/1e6:.0f}M bf16 train "
-                  f"(b{batch}xs{seq}, {dev.device_kind})",
-        "value": round(tokens_per_sec, 1),
-        "unit": "tokens/s",
-        "vs_baseline": round(mfu / 0.50, 4),
-    }))
-
-
-if __name__ == "__main__":
-    main()

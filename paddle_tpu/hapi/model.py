@@ -1,8 +1,8 @@
 """paddle.Model high-level API (ref: python/paddle/hapi/model.py (U)).
 
 fit/evaluate/predict over the dygraph core. The train loop runs through
-jit.TrainStep BY DEFAULT (r5, measured: BERT-base fit() on one chip is
-193.7 seq/s jitted vs 0.7 eager — 277x; AB_HAPI_FIT.json), with a loud
+jit.TrainStep BY DEFAULT (eager dispatches every op of every batch from
+Python; what the jitted loop gains on the chip: not measured), with a loud
 one-time fallback to eager when the forward cannot trace — pass
 `prepare(..., jit=False)` to force the reference's eager-per-batch
 behavior.

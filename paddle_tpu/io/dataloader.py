@@ -12,13 +12,13 @@ workers ship batches by pickling through mp.Queue; the reference's
 shared-memory ring is a CUDA-pinned-memory optimization with no TPU analog
 worth its fork-safety cost.
 
-Measured (benchmarks/bench_dataloader.py, single-core judge box,
-2026-07-30): numpy-heavy 375 (sync) / 377 (threads) / 22 (procs)
-samples/s; python-heavy 1141 / 1135 / 22. On a single core, workers
-cannot add parallelism — threads cost nothing while spawn processes pay
-startup+pickle, which is why threads are the default; on multi-core TPU
-VM hosts the same bench is the decision tool (process workers win only
-for GIL-holding decode when cores are plentiful).
+Threads against processes: on a single core, workers cannot add
+parallelism — threads cost nothing while spawn processes pay start-up
+and pickling, which is why threads are the default. On the many-core
+hosts of a TPU VM process workers win only for decode that holds the
+GIL, when cores are plentiful (samples/s of either: not measured; the
+benchmark's train cell runs two process workers and reads
+`trainer.input_wait_ms`).
 
 For decode-heavy Python datasets that DON'T release the GIL (jpeg decode,
 tokenization), `use_process_workers=True` switches to spawn-based process

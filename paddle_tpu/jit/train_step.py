@@ -51,9 +51,9 @@ class TrainStep:
         re-lay the params/optimizer states out once to match. Without it,
         XLA must layout-copy big weights between the conv-preferred and
         the default parameter layout EVERY step (donated aliasing pins
-        entry layout == exit layout): the r4 SD-UNet trace showed 40
-        ms/step — 40% of device time — of f32 master-weight layout flips
-        (benchmarks/profiles/unet_b4_r4.json)."""
+        entry layout == exit layout): an earlier round's SD-UNet trace
+        showed 40% of device time in f32 master-weight layout flips
+        (that trace is not in the tree; not measured since)."""
         self.model = model
         self.loss_fn = loss_fn
         self.optimizer = optimizer

@@ -106,17 +106,10 @@ class GPTAttention(Layer):
         import numpy as np
 
         if cache is not None and not isinstance(cache, (tuple, list)):
-            if hasattr(cache, "tables"):
-                # serving paged path: PagedKV — scatter this chunk's k/v
-                # into table-mapped pool blocks, ragged paged attention
-                # reads only live blocks (paddle_tpu.serving).
-                return self._forward_paged(q, k, v, cache, b, s)
-            # serving path: SlotKV slotted static-shape cache — per-row
-            # positions, dynamic_update_slice writes, full-length masked
-            # attention. One compiled decode step serves every request
-            # mix (paddle_tpu.serving); the tuple branch below stays the
-            # legacy concat-per-step cache.
-            return self._forward_slotted(q, k, v, cache, b, s)
+            # serving paged path: PagedKV — scatter this chunk's k/v
+            # into table-mapped pool blocks, ragged paged attention
+            # reads only live blocks (paddle_tpu.serving).
+            return self._forward_paged(q, k, v, cache, b, s)
 
         pos = None
         if position_offset:
@@ -140,37 +133,13 @@ class GPTAttention(Layer):
             return out, new_cache
         return out
 
-    def _forward_slotted(self, q, k, v, cache, b, s):
-        """Slotted-cache attention: write this chunk's k/v into the cache
-        rows at the per-row positions, attend over the full static-length
-        buffers under a validity mask. Bit-compatible with the concat
-        path — the same rope/attention math over the same valid keys,
-        with masked positions contributing exp(-inf) = 0."""
-        import jax.numpy as jnp
-
-        from ..serving.kv_cache import SlotKV, visible_mask, write_slots
-
-        pos = cache.pos
-        pos_ids = Tensor(pos[:, None]
-                         + jnp.arange(s, dtype=pos.dtype)[None, :])
-        q = apply_rotary_emb(q, position_ids=pos_ids, base=self.rope_theta)
-        k = apply_rotary_emb(k, position_ids=pos_ids, base=self.rope_theta)
-        k_all = write_slots(cache.k, k._data, pos)
-        v_all = write_slots(cache.v, v._data, pos)
-        mask = visible_mask(pos, s, cache.max_seq_len)
-        out = F.scaled_dot_product_attention(
-            q, Tensor(k_all), Tensor(v_all), attn_mask=Tensor(mask),
-            is_causal=False, training=self.training)
-        out = self.o_proj(M.reshape(out, [b, s, self.num_heads * self.head_dim]))
-        return out, SlotKV(k_all, v_all, pos + s)
-
     def _forward_paged(self, q, k, v, cache, b, s):
         """Paged-cache attention: rope at the per-row positions, scatter
         k/v into the lane's table-mapped pool blocks (write-before-attend
         so the current token's keys are visible to itself), then ragged
         paged attention over the block table — only blocks below each
-        lane's length are read. Bitwise-compatible with the slotted path:
-        same rope/attention math over the same visible keys. A quantized
+        lane's length are read. Same rope and attention math as the concat
+        cache's branch, over the same visible keys. A quantized
         pool (cache.k_scale set) quantizes each token at the write and
         dequantizes gathered blocks inside paged attention — same math
         over dequantized values, so parity within a quant config holds."""

@@ -6,8 +6,8 @@ LLaMA naming plus the standard config presets so users of the reference's
 ecosystem (PaddleNLP `LlamaForCausalLM`) find the same surface here.
 
 Because the attention layer is shared, LlamaAttention accepts the serving
-subsystem's cache views (the paged-pool `PagedKV` block-table view and
-the slotted static-shape `SlotKV`) anywhere the legacy `(k, v)` concat
+subsystem's cache view (the paged pool's `PagedKV`: block tables and
+per-lane positions) anywhere the legacy `(k, v)` concat
 cache is accepted — a LlamaForCausalLM drops straight into
 paddle_tpu.serving.Engine:
 

@@ -1,9 +1,9 @@
 """Stable-Diffusion-style conditional UNet (BASELINE.json config 5; the
 reference hosts it in ppdiffusers). Kept at SD-1.x topology but
-parameterized so the bench can scale it.
+parameterized so that a caller can scale it.
 
 TPU-first layout (r4): the model runs CHANNELS-LAST (NHWC) internally —
-the r4 device trace (benchmarks/profiles/unet_b4_r4.json) showed the
+an earlier round's device trace (not in the tree any more) showed the
 NCHW variant spending 50% of device time in data-formatting ops (2387
 transposes/step, 80% HBM-bound) because every TransformerBlock2D hop
 between conv [B,C,H,W] and attention [B,HW,C] materializes a physical

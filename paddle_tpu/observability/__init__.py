@@ -47,10 +47,6 @@ Phase 3 (the performance observatory):
 * :mod:`~paddle_tpu.observability.memory` — device-memory ledger
   reconciling component-accounted bytes against ``jax.live_arrays()``
   (leak-detector delta) plus the backend-bandwidth lookup.
-* :mod:`~paddle_tpu.observability.regression` — the bench-regression
-  gate comparing a fresh bench run against the committed
-  DECODE_BENCH.json (``check-bench`` CLI mode, run in CI; phase 4 adds
-  ``--bench-file`` so MULTICHIP_BENCH.json rides the same gate).
 
 Phase 4 (the mesh stack):
 
@@ -70,23 +66,16 @@ Phase 5 (the fleet observatory):
   HTTP/SSE replay harness against the serving gateway, and per-
   tenant/per-tier SLO-attainment rollups reconstructed from flight
   records.
-* :mod:`~paddle_tpu.observability.fleetsim` — discrete-event fleet
-  capacity simulator stepping the SAME trace through a modeled fleet
-  (affinity routing, priority overtake bound, ProgramCard-derived
-  service times against the backend datasheet): attainment-vs-
-  replica-count curves plus the sim-vs-live calibration report
-  FLEET_BENCH.json commits (``/debug/fleet``, CLI ``fleet`` mode).
 
 CLI: ``python -m paddle_tpu.observability
-{snapshot,prometheus,trace,programs,mesh,check-bench,fleet,serve}``.
+{snapshot,prometheus,trace,programs,mesh,serve}``.
 """
 
 from __future__ import annotations
 
-from . import (comms, events, fleetsim, loadgen, memory, metrics,
-               profiling, regression, slo, tracing)
+from . import (comms, events, loadgen, memory, metrics, profiling, slo,
+               tracing)
 from .events import export_chrome_trace
-from .fleetsim import ServiceModel
 from .loadgen import SLOSpec, WorkloadSpec, WorkloadTrace
 from .metrics import (
     Counter,
@@ -119,10 +108,10 @@ __all__ = [
     "slo", "tracing",
     "RequestTrace", "FlightRecorder", "Objective", "SLOTracker",
     "TelemetryServer",
-    "comms", "memory", "profiling", "regression",
+    "comms", "memory", "profiling",
     "MemoryLedger", "ProgramCard", "ProgramCardRegistry",
-    "loadgen", "fleetsim",
-    "WorkloadSpec", "WorkloadTrace", "SLOSpec", "ServiceModel",
+    "loadgen",
+    "WorkloadSpec", "WorkloadTrace", "SLOSpec",
 ]
 
 

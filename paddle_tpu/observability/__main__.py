@@ -10,21 +10,6 @@ Modes:
   mesh          live HybridCommunicateGroup topology (axes, dims, comm
                 rank-lists) + the collective-comms ledger, as JSON —
                 the CLI twin of the ``/debug/mesh`` endpoint
-  check-bench   bench-regression gate: compare a fresh bench document
-                (--fresh, from ``bench_decode.py --out`` or
-                ``bench_models.py bench_multichip_comms --out``)
-                against the committed baseline (--baseline /
-                --bench-file, DECODE_BENCH.json, MULTICHIP_BENCH.json
-                or FLEET_BENCH.json); exits 1 on an unallowed
-                regression
-  fleet         fleet observatory: generate seeded workload traces
-                (--shapes, e.g. chat,mixed), run the discrete-event
-                capacity simulator across --replicas, and print
-                SLO-attainment-vs-replica-count curves as JSON; with
-                --live, also replay the first shape against real
-                CPU-proxy gateways over HTTP/SSE and attach the
-                sim-vs-live calibration report (exits 1 when the
-                calibration gate fails)
   serve         start the telemetry HTTP endpoint (blocks; --port,
                 --duration to exit after N seconds)
 
@@ -48,73 +33,22 @@ def main(argv=None):
         description="dump paddle_tpu observability state")
     parser.add_argument("mode", nargs="?", default="snapshot",
                         choices=("snapshot", "prometheus", "trace",
-                                 "programs", "mesh", "check-bench",
-                                 "fleet", "serve"))
+                                 "programs", "mesh", "serve"))
     parser.add_argument("-o", "--output", default=None,
                         help="write to FILE instead of stdout")
     parser.add_argument("--exec", dest="script", default=None,
                         help="run a Python script first, then dump")
     parser.add_argument("--json", action="store_true",
                         help="programs mode: raw JSON instead of a table")
-    parser.add_argument("--baseline", default="DECODE_BENCH.json",
-                        help="check-bench: committed baseline document")
-    parser.add_argument("--bench-file", default=None,
-                        help="check-bench: gate against this committed "
-                        "bench document instead of --baseline (e.g. "
-                        "MULTICHIP_BENCH.json)")
-    parser.add_argument("--fresh", default=None,
-                        help="check-bench: fresh bench document "
-                        "(bench_decode.py --out FILE)")
-    parser.add_argument("--tolerance", type=float, default=0.25,
-                        help="check-bench: relative tolerance on the "
-                        "timing-derived primary value (0.25 = 25%%)")
-    parser.add_argument("--det-tolerance", type=float, default=0.0,
-                        help="check-bench: tolerance on deterministic "
-                        "fields (bytes/compile/dispatch counts)")
-    parser.add_argument("--allow-regress", action="append", default=[],
-                        help="check-bench: substring of metric[::field] "
-                        "whose regression is acknowledged (repeatable)")
     parser.add_argument("--port", type=int, default=9400,
                         help="serve mode: port to bind (0 = ephemeral)")
     parser.add_argument("--duration", type=float, default=None,
                         help="serve mode: exit after N seconds "
                         "(default: serve until interrupted)")
-    parser.add_argument("--shapes", default="chat,mixed",
-                        help="fleet mode: comma-separated workload "
-                        "shapes (chat, mixed)")
-    parser.add_argument("--replicas", default="1,2,4",
-                        help="fleet mode: comma-separated replica "
-                        "counts for the attainment curve")
-    parser.add_argument("--requests", type=int, default=48,
-                        help="fleet mode: requests per workload trace")
-    parser.add_argument("--seed", type=int, default=0,
-                        help="fleet mode: workload trace seed")
-    parser.add_argument("--live", action="store_true",
-                        help="fleet mode: also replay against live "
-                        "CPU-proxy gateways and attach the sim-vs-live "
-                        "calibration report")
-    parser.add_argument("--speed", type=float, default=4.0,
-                        help="fleet mode: virtual-time compression for "
-                        "replay/sim timelines (higher = burstier wall-"
-                        "clock load; keep moderate with --live so the "
-                        "shared-core CPU proxy stays uncontended)")
-    parser.add_argument("--slo-ttft", type=float, default=2.0,
-                        help="fleet mode: TTFT attainment threshold "
-                        "(wall seconds at replay speed)")
-    parser.add_argument("--slo-tpot", type=float, default=0.5,
-                        help="fleet mode: per-token attainment "
-                        "threshold (wall seconds)")
-    parser.add_argument("--fleet-tolerance", type=float, default=0.25,
-                        help="fleet mode: sim-vs-live attainment "
-                        "tolerance for the calibration gate")
     args = parser.parse_args(argv)
 
     if args.mode == "serve":
         return _serve(args)
-    if args.mode == "check-bench":
-        return _check_bench(args)
-    if args.mode == "fleet":
-        return _fleet(args)
 
     if args.script:
         with open(args.script) as f:
@@ -145,47 +79,6 @@ def main(argv=None):
     else:
         sys.stdout.write(text if text.endswith("\n") else text + "\n")
     return 0
-
-
-def _check_bench(args):
-    from . import regression
-
-    if not args.fresh:
-        print("check-bench: --fresh FILE is required "
-              "(produce one with benchmarks/bench_decode.py --out)",
-              file=sys.stderr)
-        return 2
-    report = regression.check_bench(
-        args.baseline, args.fresh, tolerance=args.tolerance,
-        det_tolerance=args.det_tolerance,
-        allow_regress=args.allow_regress,
-        bench_file=args.bench_file)
-    text = regression.render_text(report)
-    if args.output:
-        with open(args.output, "w") as f:
-            f.write(json.dumps(report, indent=2) + "\n")
-    sys.stdout.write(text)
-    return 0 if report["ok"] else 1
-
-
-def _fleet(args):
-    from . import fleetsim, loadgen
-
-    report = fleetsim.fleet_report(
-        shapes=[s.strip() for s in args.shapes.split(",") if s.strip()],
-        replica_counts=[int(n) for n in args.replicas.split(",")],
-        n_requests=args.requests, seed=args.seed, live=args.live,
-        speed=args.speed,
-        slo=loadgen.SLOSpec(ttft_s=args.slo_ttft,
-                            tpot_s=args.slo_tpot),
-        tolerance=args.fleet_tolerance)
-    text = json.dumps(report, indent=2, default=repr) + "\n"
-    if args.output:
-        with open(args.output, "w") as f:
-            f.write(text)
-    else:
-        sys.stdout.write(text)
-    return 0 if report["ok"] else 1
 
 
 def _serve(args):

@@ -41,10 +41,10 @@ LIVE serving gateway over real HTTP/SSE (``speed`` compresses virtual
 time so a 5-minute trace replays in seconds), then reconstruct
 per-phase latency — queue wait, prefill/TTFT, decode TPOT — from the
 engines' RequestTrace flight records and aggregate SLO attainment per
-tenant and per priority tier with :func:`summarize`.  The same
-``summarize`` consumes the capacity simulator's output
-(:mod:`~paddle_tpu.observability.fleetsim`), so sim-vs-live
-calibration compares like with like.
+tenant and per priority tier with :func:`summarize`.  ``summarize``
+takes any list of per-request records with these fields, so a
+modelled run and a live one can be rolled up alike
+(tests/test_fleet_observatory.py builds such records by hand).
 """
 
 from __future__ import annotations
@@ -200,7 +200,7 @@ class WorkloadTrace:
 
     def digest(self):
         """sha256 of the canonical serialization — the workload's
-        provenance stamp (FLEET_BENCH rows carry it)."""
+        provenance stamp (tests/test_fleet_observatory.py pins three)."""
         return hashlib.sha256(self.to_json().encode()).hexdigest()
 
     @classmethod

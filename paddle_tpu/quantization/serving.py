@@ -1,7 +1,7 @@
 """Weight-only PTQ for the serving engine (int8 decode weights).
 
-Decode is weight-bandwidth-bound (DECODE_BENCH.json: fused decode caps
-near 47% of the weight roofline), so the cheapest 2x on the bound is
+Decode streams every weight once a step and is bound by that stream
+(`PERF.md` section 5), so the cheapest 2x on the bound is
 storing matmul weights as int8 and paying a per-channel multiply to
 rebuild them inside the program: XLA fuses ``q.astype(f32) * scale``
 into the matmul's weight read, so the bytes streamed from HBM per step

@@ -2,15 +2,14 @@
 
 The serving subsystem the reference ships as AnalysisPredictor + the
 fused CUDA decode ops (fused_multi_transformer), rebuilt TPU-native
-around three ideas the benches point at (DECODE_BENCH.json):
+around these ideas:
 
 * a **unified paged KV pool** (kv_cache.py) — all KV in ONE per-layer
   ``[num_blocks, block_size, kv_heads, head_dim]`` pool (vLLM-style
   fixed blocks) addressed through per-slot block tables; table entries
   are allocated lazily, so HBM scales with live tokens, and every
   block is host-refcounted (table entries and the prefix store each
-  hold a reference).  The slotted static-shape cache
-  (:class:`SlottedKVCache`) remains as the simpler reference design;
+  hold a reference);
 * **ragged paged-attention decode** (paged_attention.py) — decode
   attention reads ONLY each lane's table-mapped blocks (Pallas kernel
   on TPU, an XLA online-softmax fallback on CPU whose exact-zero
@@ -120,8 +119,7 @@ from .faults import (FaultInjector, FaultPlan, FaultSpec, RetryPolicy,
                      TransientSubmitError, WorkerCrash, WorkerDeadError)
 from .gateway import (EngineWorker, FleetSupervisor, Gateway,
                       GatewayConfig, PrefixAffinityRouter, TenantQuotas)
-from .kv_cache import (PagedKV, PagedKVCache, PagedKVPool, SlotKV,
-                       SlottedKVCache)
+from .kv_cache import PagedKV, PagedKVCache, PagedKVPool
 from .kv_host_tier import HostKVTier
 from .paged_attention import paged_attention
 from .prefix_cache import PrefixCache, PrefixLease
@@ -136,7 +134,6 @@ __all__ = [
     "Engine", "EngineConfig", "CompiledFn",
     "PagedKV", "PagedKVCache", "PagedKVPool", "paged_attention",
     "HostKVTier",
-    "SlotKV", "SlottedKVCache",
     "PrefixCache", "PrefixLease",
     "SamplingParams", "Request", "Scheduler",
     "draft_tokens", "forced_chain",

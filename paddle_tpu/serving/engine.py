@@ -452,8 +452,8 @@ class EngineConfig:
     #: ragged decode attention: bucket the decode program's block-table
     #: width to a power of two of the deepest live row, so per-step KV
     #: reads track live sequence length.  False pins the width to
-    #: max_blocks_per_slot — the slotted-bandwidth ablation knob
-    #: (benchmarks/bench_decode.py measures both).
+    #: max_blocks_per_slot: every decode step then reads the full table
+    #: width (tests/test_serving_counts.py holds both counts, in bytes).
     ragged_attention: bool = True
     #: speculative decoding: max draft tokens per lane per fused step.
     #: 0 = plain decode.  K > 0 self-drafts K tokens per lane from its
@@ -507,7 +507,7 @@ class EngineConfig:
     #: (all live traces + the last ``flight_recorder_capacity``
     #: finished ones) and served at /debug/requests.  Appends are O(1)
     #: per lifecycle transition, so the decode path cost is bounded
-    #: (bench_decode's tracing-overhead section measures it).
+    #: (what it costs on the chip: not measured).
     request_tracing: bool = True
     flight_recorder_capacity: int = 256
     #: program cards: capture XLA cost/memory analysis, compile seconds,

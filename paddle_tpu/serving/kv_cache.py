@@ -1,18 +1,9 @@
-"""KV caches for continuous-batching decode: the slotted static-shape
-cache and the unified paged block pool.
+"""The KV cache for continuous-batching decode: one paged block pool.
 
 The legacy decode path in models/gpt.py grows a `(k, v)` concat cache by
 one position per step, so every step has a new shape and eager decode
-retraces constantly (DECODE_BENCH.json: ~2.6 ms/token against a 0.77 ms
-weight roofline).  Two static-shape cache designs fix that:
+retraces at every token.  The serving engine keeps static shapes instead:
 
-* **Slotted rows** (:class:`SlottedKVCache` + :class:`SlotKV`) — one
-  per-layer ``[num_slots, max_seq_len, kv_heads, head_dim]`` buffer,
-  written in place via ``lax.dynamic_update_slice``.  Simple, but every
-  decode step attends position-masked over the FULL ``max_seq_len`` row,
-  so short sequences pay bandwidth for the whole row, and a separate
-  prefix-cache pool needs per-admission gathers to bridge the two
-  allocations.
 * **Paged pool** (:class:`PagedKVPool` + :class:`PagedKV`) — ONE
   per-layer ``[num_blocks, block_size, kv_heads, head_dim]`` pool
   (vLLM-style fixed blocks) shared by every slot AND the prefix cache,
@@ -20,9 +11,7 @@ weight roofline).  Two static-shape cache designs fix that:
   the table-mapped blocks below each row's length (ragged), prefix hits
   lease cached blocks straight into a slot's table (copy-free,
   refcounted), and preempting an idle sequence is just releasing its
-  table entries.  This is the serving engine's cache since the unified-
-  pool refactor; the slotted classes remain for the model-level parity
-  tests and as the simpler reference design.
+  table entries.
 
 All device-side helpers are pure jnp functions so they trace into one
 XLA program.
@@ -38,9 +27,7 @@ written is finite, and the row's visible window is bounded by ``pos``.
 After a slot retires, the engine zeroes its block-table row, so any
 further masked-lane writes land in the reserved scratch block 0 — slot
 reuse never depends on overwriting stale rows, the freed blocks simply
-return to the pool.  (The slotted cache relied on the analogous masking
-argument: stale row positions sit at indices >= the new occupant's
-length until prefill re-writes them.)
+return to the pool.
 """
 
 from __future__ import annotations
@@ -51,97 +38,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-
-
-@dataclass
-class SlotKV:
-    """One layer's slotted-cache view for a batch of slot rows.
-
-    k, v: [batch, max_seq_len, kv_heads, head_dim] cache buffers
-    pos:  [batch] int32 — the write position per row (== number of tokens
-          already cached in that row); the incoming tokens are written at
-          positions pos .. pos+s-1 and attend over keys 0 .. pos+s-1.
-    """
-
-    k: jax.Array
-    v: jax.Array
-    pos: jax.Array
-
-    @property
-    def max_seq_len(self):
-        return self.k.shape[1]
-
-
-def write_slots(cache, new, pos):
-    """Write ``new`` [B, s, H, D] into ``cache`` [B, S_max, H, D] at
-    per-row positions ``pos`` [B] via dynamic_update_slice (in-place in
-    HBM under jit when the buffer is donated)."""
-    def upd(c, n, p):
-        return jax.lax.dynamic_update_slice(
-            c, n.astype(c.dtype), (p.astype(jnp.int32), 0, 0))
-
-    return jax.vmap(upd)(cache, new, pos)
-
-
-def visible_mask(pos, s, max_seq_len):
-    """Boolean attention mask [B, 1, s, S_max]: query i of row b (absolute
-    position pos[b]+i) sees cache keys at positions <= pos[b]+i.  Padded
-    prompt tail and stale tokens from a previous slot occupant sit at
-    positions >= the row's current length, so they are always masked."""
-    q_pos = pos[:, None] + jnp.arange(s, dtype=pos.dtype)        # [B, s]
-    key_idx = jnp.arange(max_seq_len, dtype=pos.dtype)           # [S_max]
-    return key_idx[None, None, None, :] <= q_pos[:, None, :, None]
-
-
-class SlottedKVCache:
-    """Engine-owned per-layer slotted buffers + the slot free-list.
-
-    The arrays live as plain jax arrays (not Tensors) so the engine can
-    pass them straight into its jitted prefill/decode programs and donate
-    them for in-place updates.
-    """
-
-    def __init__(self, num_layers, num_slots, max_seq_len, kv_heads,
-                 head_dim, dtype=jnp.float32):
-        self.num_layers = num_layers
-        self.num_slots = num_slots
-        self.max_seq_len = max_seq_len
-        self.kv_heads = kv_heads
-        self.head_dim = head_dim
-        self.dtype = dtype
-        shape = (num_slots, max_seq_len, kv_heads, head_dim)
-        self.k = [jnp.zeros(shape, dtype) for _ in range(num_layers)]
-        self.v = [jnp.zeros(shape, dtype) for _ in range(num_layers)]
-        self._free = list(range(num_slots - 1, -1, -1))
-
-    # ---------------- slot bookkeeping (host side)
-    def alloc(self):
-        """Claim a free slot index, or None when the cache is full."""
-        return self._free.pop() if self._free else None
-
-    def free(self, slot):
-        if slot in self._free:
-            raise ValueError(f"slot {slot} double-freed")
-        self._free.append(slot)
-
-    @property
-    def free_slots(self):
-        return len(self._free)
-
-    @property
-    def used_slots(self):
-        return self.num_slots - len(self._free)
-
-    def layer_views(self, pos):
-        """Per-layer SlotKV views over ALL slots (the fused decode step
-        runs every slot; inactive rows are masked by their pos)."""
-        return [SlotKV(self.k[i], self.v[i], pos)
-                for i in range(self.num_layers)]
-
-    def rebind(self, new_k, new_v):
-        """Adopt updated buffers returned by a jitted program."""
-        self.k = list(new_k)
-        self.v = list(new_v)
 
 
 # --------------------------------------------------------------- paged

@@ -1,10 +1,10 @@
 """Ragged paged-attention over the unified KV block pool.
 
-Decode attention against a slotted cache reads the full ``max_seq``
-row of every lane under a position mask — short sequences pay bandwidth
-for the whole row (DECODE_BENCH.json: fused decode stuck at 41-47% of
-the weight roofline at b1 and 25.5% at b8, where the masked reads are 8
-full rows per step).  Paged attention instead walks each lane's block
+A cache of one full-length row a lane makes decode attention read the
+whole ``max_seq`` row of every lane under a position mask: short
+sequences pay bandwidth for the whole row, and a batch of 8 reads 8
+full rows a step (its cost on the chip: not measured; ``PERF.md`` has
+this kernel's numbers).  Paged attention instead walks each lane's block
 table and reads ONLY the table-mapped blocks, so per-step KV traffic is
 proportional to the live sequence length.
 

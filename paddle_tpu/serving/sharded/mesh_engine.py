@@ -26,8 +26,8 @@ forward for MHA and GQA, prefill and decode shapes):
 * every other combine is ``lax.all_gather(tiled=True)`` — a pure
   concatenation in shard order, which moves bytes, never re-rounds.
 
-Decode-program collective census (hand-derived, gated EXACT by
-check-bench against MULTICHIP_BENCH.json): per layer per scanned step,
+Decode-program collective census (hand-derived, held EXACT by
+tests/test_comms_observability.py): per layer per scanned step,
 1 psum (head combine) + 3 all_gathers (o_proj out, SwiGLU intermediate,
 down_proj out), plus 1 all_gather per step for the lm_head logits — so
 a horizon-``h`` dispatch over ``L`` layers counts ``psum@tp = L*h`` and
@@ -327,7 +327,7 @@ class MeshEngine(Engine):
     # ------------------------------------------------------------ census
     def expected_decode_census(self, horizon=None, k_draft=0):
         """The hand-derived collective census of one compiled decode
-        dispatch — the contract MULTICHIP_BENCH.json gates EXACT.  Per
+        dispatch, which the census tests hold the walker to.  Per
         scanned step: L psums (head combines) + 3L+1 all_gathers
         (o_proj, SwiGLU intermediate, down_proj per layer; lm_head
         once); int8 KV adds 2L pmaxes (k and v absmax per layer)."""

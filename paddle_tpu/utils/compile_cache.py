@@ -2,8 +2,8 @@
 outside.
 
 Entry points that run on the chip call :func:`enable` once, before their
-first compile (``chip_smoke.py``, ``bench.py``, the ``benchmarks/``
-drivers, a training script under the launcher).  ``import paddle_tpu``
+first compile (``chip_smoke.py``, the benchmark's programs under
+``chipbench/``, a training script under the launcher).  ``import paddle_tpu``
 does not: the tests stay cache-free.
 
 The directory is part of every cache key's lookup, so it must not move

@@ -67,16 +67,11 @@ class TestNoFallback:
             assert device.set_device("gpu:0").platform == "cpu"
         assert paddle.device.get_device().startswith("cpu")
 
-    def test_bench_main_refuses_a_cpu(self):
-        r = _run([os.path.join(_ROOT, "bench.py")], {"JAX_PLATFORMS": "cpu"})
-        assert r.returncode != 0
-        assert "tokens/s" not in r.stdout
-
 
 class TestOneProcessPerChip:
     def test_imports_initialize_no_backend(self):
         """A parent that only imports the package (the launcher, a
-        DataLoader worker, a bench driver) must leave the chip to its
+        DataLoader worker, a benchmark driver) must leave the chip to its
         child."""
         code = (
             "import paddle_tpu, paddle_tpu.serving, paddle_tpu.jit\n"
@@ -234,7 +229,7 @@ class TestPhasesOnCpu:
         assert row["compiled"] is False
         assert {"paged_attention_s16", "paged_attention_int8_s1",
                 "rms_norm_dx", "layer_norm_db", "group_norm_dw",
-                "bench.flash_fwd_causal1"} <= set(row["errors"])
+                "kernel_checks.flash_fwd_causal1"} <= set(row["errors"])
 
     def test_tp_phase_on_virtual_devices(self):
         """Rehearsal 2: the tensor-parallel path on virtual CPU devices."""

@@ -1,4 +1,4 @@
-"""The bench kernel-smoke gate itself: every check passes in interpret mode,
+"""The kernel-smoke gate itself: every check passes in interpret mode,
 and a seeded perturbation of ANY kernel's result trips the gate loudly
 (VERDICT r2 item 3 — the gate must be proven able to fail)."""
 
@@ -9,7 +9,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-import bench  # noqa: E402
+import kernel_checks  # noqa: E402
 
 _NAMES = []
 
@@ -18,13 +18,13 @@ def _names():
     # one full suite execution, shared by every parametrized case (each
     # yield of _kernel_checks computes real kernels — it is not free)
     if not _NAMES:
-        _NAMES.extend(n for n, _, _ in bench._kernel_checks())
+        _NAMES.extend(n for n, _, _ in kernel_checks._kernel_checks())
     return _NAMES
 
 
 def test_all_checks_pass_clean():
     seen = []
-    for name, err, tol in bench._kernel_checks():
+    for name, err, tol in kernel_checks._kernel_checks():
         assert err < tol, f"{name}: {err} >= {tol}"
         seen.append(name)
     if not _NAMES:  # reuse this run for the parametrized cases below
@@ -42,7 +42,7 @@ def test_gate_trips_on_perturbation(name):
     names = _names()
     assert name in names, f"{name} not in gate: {names}"
     with pytest.raises(AssertionError, match=name):
-        bench.kernel_smoke(perturb=name)
+        kernel_checks.kernel_smoke(perturb=name)
 
 
 def test_gate_covers_backward_paths():

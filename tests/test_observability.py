@@ -444,6 +444,20 @@ class TestCLI:
         assert out.returncode == 0, out.stderr
         assert "# TYPE cli_h histogram" in out.stdout
 
+    def test_modes_are_dumps_and_serve(self, capsys):
+        """The CLI dumps live state or serves it, and nothing else: it
+        gates no timing and runs no simulator."""
+        from paddle_tpu.observability.__main__ import main
+
+        with pytest.raises(SystemExit) as exc:
+            main(["--help"])
+        assert exc.value.code == 0
+        assert ("{snapshot,prometheus,trace,programs,mesh,serve}"
+                in capsys.readouterr().out)
+        with pytest.raises(SystemExit) as exc:
+            main(["fleet"])
+        assert exc.value.code == 2
+
 
 class TestFlightRecorder:
     """RequestTrace flight records + bounded FlightRecorder retention."""
@@ -997,149 +1011,8 @@ class TestMemoryLedger:
         assert a == b and a > 0               # one probe per process
 
 
-class TestRegressionGate:
-    """Phase 3 bench-regression gate over synthetic fixtures."""
-
-    @staticmethod
-    def _doc(tok_s=100.0, ttft_ms=50.0, kv_bytes=4096,
-             decode_compiles=2):
-        return {"backend": "cpu", "results": [
-            {"metric": "engine decode tokens/s b1 (cpu)",
-             "value": tok_s, "unit": "tokens/s",
-             "kv_bytes_read_per_step": kv_bytes,
-             "decode_compiles": decode_compiles},
-            {"metric": "engine ttft (cpu)",
-             "value": ttft_ms, "unit": "ms"},
-        ]}
-
-    def test_identical_docs_pass(self):
-        from paddle_tpu.observability import regression
-
-        rep = regression.compare(self._doc(), self._doc(), tolerance=0.0)
-        assert rep["ok"] and rep["regressions"] == 0
-        assert rep["compared_metrics"] == 2
-        assert rep["compared_values"] == 4    # 2 values + 2 det fields
-        assert regression.render_text(rep).rstrip().endswith("PASS")
-
-    def test_injected_20pct_tok_s_regression_detected(self):
-        """The acceptance fixture: 20% tok/s drop must trip a 10%
-        tolerance gate, and the finding must carry the numbers."""
-        from paddle_tpu.observability import regression
-
-        rep = regression.compare(self._doc(tok_s=100.0),
-                                 self._doc(tok_s=80.0), tolerance=0.10)
-        assert not rep["ok"] and rep["regressions"] == 1
-        f = rep["findings"][0]
-        assert f["field"] == "value"
-        assert f["regression_pct"] == pytest.approx(20.0)
-        assert f["direction"] == "higher_is_better"
-        assert "FAIL: 1 regression(s)" in regression.render_text(rep)
-        # the same drop under a generous tolerance passes
-        rep = regression.compare(self._doc(tok_s=100.0),
-                                 self._doc(tok_s=80.0), tolerance=0.25)
-        assert rep["ok"]
-        # tok/s going UP is an improvement, never a finding
-        rep = regression.compare(self._doc(tok_s=100.0),
-                                 self._doc(tok_s=130.0), tolerance=0.10)
-        assert rep["ok"] and not rep["findings"]
-
-    def test_latency_direction_from_unit(self):
-        from paddle_tpu.observability import regression
-
-        assert regression.higher_is_better("tokens/s")
-        assert not regression.higher_is_better("ms")
-        assert not regression.higher_is_better("s avg ttft")
-        # ttft (ms) rising 40% trips; falling is an improvement
-        rep = regression.compare(self._doc(ttft_ms=50.0),
-                                 self._doc(ttft_ms=70.0), tolerance=0.10)
-        assert not rep["ok"]
-        assert rep["findings"][0]["metric"] == "engine ttft (cpu)"
-        rep = regression.compare(self._doc(ttft_ms=50.0),
-                                 self._doc(ttft_ms=30.0), tolerance=0.10)
-        assert rep["ok"]
-
-    def test_deterministic_fields_gate_exact(self):
-        """KV traffic doubling fails at det_tolerance=0 even when tok/s
-        noise hides it behind the loose value tolerance."""
-        from paddle_tpu.observability import regression
-
-        rep = regression.compare(self._doc(kv_bytes=4096),
-                                 self._doc(kv_bytes=8192),
-                                 tolerance=0.5, det_tolerance=0.0)
-        assert not rep["ok"]
-        assert rep["findings"][0]["field"] == "kv_bytes_read_per_step"
-        # compile-count creep is likewise deterministic
-        rep = regression.compare(self._doc(decode_compiles=2),
-                                 self._doc(decode_compiles=3),
-                                 tolerance=0.5)
-        assert not rep["ok"]
-        assert rep["findings"][0]["field"] == "decode_compiles"
-        # det_tolerance loosens it explicitly
-        rep = regression.compare(self._doc(decode_compiles=2),
-                                 self._doc(decode_compiles=3),
-                                 tolerance=0.5, det_tolerance=0.6)
-        assert rep["ok"]
-
-    def test_allow_regress_acknowledges(self):
-        from paddle_tpu.observability import regression
-
-        rep = regression.compare(
-            self._doc(tok_s=100.0), self._doc(tok_s=70.0),
-            tolerance=0.10,
-            allow_regress=["decode tokens/s b1 (cpu)::value"])
-        assert rep["ok"] and rep["regressions"] == 0
-        assert rep["allowed_regressions"] == 1
-        assert rep["findings"][0]["allowed"]
-        assert "ALLOWED" in regression.render_text(rep)
-        # the allowlist is per metric::field, not a blanket waiver
-        rep = regression.compare(
-            self._doc(tok_s=70.0, ttft_ms=90.0), self._doc(tok_s=70.0,
-                                                           ttft_ms=90.0))
-        assert rep["ok"]
-
-    def test_only_shared_metrics_gate(self):
-        """A --only fresh run re-measures one section; baseline-only
-        rows are skipped and listed, never failed."""
-        from paddle_tpu.observability import regression
-
-        fresh = {"results": [self._doc()["results"][0]]}
-        rep = regression.compare(self._doc(), fresh, tolerance=0.0)
-        assert rep["ok"] and rep["compared_metrics"] == 1
-        assert rep["skipped_baseline_only"] == ["engine ttft (cpu)"]
-        extra = {"results": self._doc()["results"] + [
-            {"metric": "brand new (cpu)", "value": 1.0, "unit": "x"}]}
-        rep = regression.compare(self._doc(), extra, tolerance=0.0)
-        assert rep["skipped_fresh_only"] == ["brand new (cpu)"]
-
-    def test_check_bench_files(self, tmp_path):
-        from paddle_tpu.observability import regression
-
-        b = tmp_path / "base.json"
-        f = tmp_path / "fresh.json"
-        b.write_text(json.dumps(self._doc()))
-        f.write_text(json.dumps(self._doc(tok_s=75.0)))
-        rep = regression.check_bench(str(b), str(f), tolerance=0.10)
-        assert not rep["ok"]
-        assert rep["baseline"] == str(b) and rep["fresh"] == str(f)
-
-    def test_committed_bench_self_check_passes(self):
-        """The committed DECODE_BENCH.json gates cleanly against
-        itself (the CI job's degenerate case)."""
-        import os
-
-        from paddle_tpu.observability import regression
-
-        path = os.path.join(os.path.dirname(__file__), os.pardir,
-                            "DECODE_BENCH.json")
-        doc = regression.load(path)
-        rep = regression.compare(doc, doc, tolerance=0.0,
-                                 det_tolerance=0.0)
-        assert rep["ok"] and rep["regressions"] == 0
-        assert rep["compared_metrics"] > 10
-
-
 class TestProgramsEndpointAndCLI:
-    """/debug/programs routing + the programs / check-bench CLI modes."""
+    """/debug/programs routing + the programs CLI mode."""
 
     def test_debug_programs_route(self):
         import jax
@@ -1190,41 +1063,6 @@ class TestProgramsEndpointAndCLI:
         doc = json.loads(out.stdout)
         assert doc["cards"][0]["fn"] == "cli.prog"
         assert doc["cards"][0]["flops"] > 0
-
-    @pytest.mark.slow
-    def test_check_bench_cli_mode(self, tmp_path):
-        base = tmp_path / "base.json"
-        fresh = tmp_path / "fresh.json"
-        row = {"metric": "m (cpu)", "value": 100.0, "unit": "tokens/s"}
-        base.write_text(json.dumps({"results": [row]}))
-        fresh.write_text(json.dumps(
-            {"results": [{**row, "value": 79.0}]}))
-        env = {**__import__("os").environ, "JAX_PLATFORMS": "cpu"}
-
-        def run(*extra):
-            return subprocess.run(
-                [sys.executable, "-m", "paddle_tpu.observability",
-                 "check-bench", "--baseline", str(base), *extra],
-                capture_output=True, text=True, timeout=120, env=env)
-
-        # missing --fresh is usage error 2
-        assert run().returncode == 2
-        # 21% drop vs 10% tolerance: rc 1, FAIL rendered
-        out = run("--fresh", str(fresh), "--tolerance", "0.10")
-        assert out.returncode == 1, out.stderr
-        assert "FAIL: 1 regression(s)" in out.stdout
-        # allow-regress turns the same comparison green
-        report = tmp_path / "report.json"
-        out = run("--fresh", str(fresh), "--tolerance", "0.10",
-                  "--allow-regress", "m (cpu)::value",
-                  "-o", str(report))
-        assert out.returncode == 0, out.stderr
-        assert "PASS" in out.stdout
-        rep = json.loads(report.read_text())
-        assert rep["ok"] and rep["allowed_regressions"] == 1
-        # baseline vs itself: rc 0
-        out = run("--fresh", str(base), "--tolerance", "0.0")
-        assert out.returncode == 0, out.stderr
 
 
 class TestTelemetryServerLifecycle:

@@ -1,6 +1,9 @@
-"""paddle_tpu.serving tests: slotted-cache decode parity with the legacy
+"""paddle_tpu.serving tests: paged-engine decode parity with the legacy
 concat cache, continuous batching vs sequential generation, bucketed
-prefill compilation counters, sampling determinism."""
+prefill compilation counters, sampling determinism.  The exact counts of
+each mechanism (dispatches, compiles, KV bytes, swap bytes, accept lengths,
+collective calls) are in test_serving_counts.py: a file of its own, so
+that another worker takes it (this file is the suite's longest)."""
 
 import functools
 
@@ -16,15 +19,13 @@ from paddle_tpu.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.models.llama import LlamaForCausalLM
 from paddle_tpu.serving import (
     Engine, EngineConfig, HostKVTier, PagedKVCache, PagedKVPool,
-    PrefixCache, SamplingParams, Scheduler, SlotKV, SlottedKVCache,
+    PrefixCache, SamplingParams, Scheduler,
 )
 from paddle_tpu.quantization import (
     PerChannelAbsmaxObserver, channelwise_scales, dequantize_weight,
     quantize_for_serving, quantize_weight,
 )
-from paddle_tpu.serving.kv_cache import (
-    paged_write, paged_write_quant, visible_mask, write_slots,
-)
+from paddle_tpu.serving.kv_cache import paged_write, paged_write_quant
 from paddle_tpu.serving.paged_attention import (
     _pallas_paged_attention, _xla_paged_attention,
 )
@@ -42,98 +43,6 @@ def _model(cfg=TINY, seed=0):
     m = GPTForCausalLM(cfg)
     m.eval()
     return m
-
-
-def _fresh_views(cfg, b, max_seq, n_layers):
-    shape = (b, max_seq, cfg.kv_heads, cfg.head_dim)
-    pos = jnp.zeros((b,), jnp.int32)
-    return [SlotKV(jnp.zeros(shape, jnp.float32),
-                   jnp.zeros(shape, jnp.float32), pos)
-            for _ in range(n_layers)]
-
-
-class TestSlottedCacheParity:
-    """The slotted static-shape cache must reproduce the legacy
-    concat-per-step cache decode."""
-
-    def test_prefill_logits_bit_identical(self):
-        m = _model()
-        ids = paddle.randint(0, TINY.vocab_size, [2, 6])
-        with _tape.no_grad():
-            h1, _ = m.model(ids, caches=[(None, None)] * 2)
-            h2, _ = m.model(ids, caches=_fresh_views(TINY, 2, 24, 2))
-            l1 = m._logits(h1).numpy()
-            l2 = m._logits(h2).numpy()
-        # same shapes, same math, cache-write side effects only: the
-        # prompt pass is bitwise identical
-        np.testing.assert_array_equal(l1, l2)
-
-    @pytest.mark.parametrize("cfg", [TINY, TINY_GQA], ids=["mha", "gqa"])
-    def test_decode_matches_concat_cache(self, cfg):
-        """Greedy decode over both cache kinds: token streams identical,
-        per-step logits equal to reduction-order rounding (the slotted
-        path sums exp(-inf)=0 terms over the padded tail, which may
-        re-associate the reduction — observed <=2 ulp on CPU)."""
-        m = _model(cfg)
-        b, s, steps, max_seq = 2, 6, 8, 24
-        ids = paddle.randint(0, cfg.vocab_size, [b, s])
-        with _tape.no_grad():
-            h1, concat = m.model(ids, caches=[(None, None)] * 2)
-            h2, slotted = m.model(ids, caches=_fresh_views(cfg, b, max_seq, 2))
-            t1 = paddle.argmax(m._logits(h1)[:, -1], axis=-1)
-            t2 = paddle.argmax(m._logits(h2)[:, -1], axis=-1)
-            np.testing.assert_array_equal(t1.numpy(), t2.numpy())
-            for step in range(steps):
-                h1, concat = m.model(t1.unsqueeze(-1), caches=concat,
-                                     position_offset=s + step)
-                h2, slotted = m.model(t2.unsqueeze(-1), caches=slotted)
-                l1 = m._logits(h1)[:, -1]
-                l2 = m._logits(h2)[:, -1]
-                np.testing.assert_allclose(l1.numpy(), l2.numpy(),
-                                           rtol=0, atol=1e-5)
-                t1 = paddle.argmax(l1, axis=-1)
-                t2 = paddle.argmax(l2, axis=-1)
-                np.testing.assert_array_equal(t1.numpy(), t2.numpy())
-
-    def test_slot_positions_advance(self):
-        m = _model()
-        views = _fresh_views(TINY, 2, 24, 2)
-        ids = paddle.randint(0, TINY.vocab_size, [2, 5])
-        with _tape.no_grad():
-            _, views = m.model(ids, caches=views)
-        assert np.asarray(views[0].pos).tolist() == [5, 5]
-        with _tape.no_grad():
-            _, views = m.model(paddle.randint(0, 128, [2, 1]), caches=views)
-        assert np.asarray(views[0].pos).tolist() == [6, 6]
-
-
-class TestKVCacheHelpers:
-    def test_write_slots_per_row_positions(self):
-        cache = jnp.zeros((2, 8, 1, 4))
-        new = jnp.ones((2, 1, 1, 4))
-        out = write_slots(cache, new, jnp.asarray([0, 5], jnp.int32))
-        out = np.asarray(out)
-        assert out[0, 0].sum() == 4 and out[0, 1:].sum() == 0
-        assert out[1, 5].sum() == 4 and out[1, :5].sum() == 0
-
-    def test_visible_mask_is_causal_per_row(self):
-        mask = np.asarray(visible_mask(jnp.asarray([0, 3], jnp.int32), 2, 8))
-        assert mask.shape == (2, 1, 2, 8)
-        # row 0: queries at absolute positions 0,1
-        assert mask[0, 0, 0].tolist() == [True] + [False] * 7
-        assert mask[0, 0, 1].tolist() == [True, True] + [False] * 6
-        # row 1: queries at absolute positions 3,4
-        assert mask[1, 0, 0].tolist() == [True] * 4 + [False] * 4
-        assert mask[1, 0, 1].tolist() == [True] * 5 + [False] * 3
-
-    def test_slot_alloc_free(self):
-        c = SlottedKVCache(1, 2, 8, 1, 4)
-        a, b = c.alloc(), c.alloc()
-        assert {a, b} == {0, 1} and c.alloc() is None
-        c.free(a)
-        assert c.free_slots == 1 and c.used_slots == 1
-        with pytest.raises(ValueError):
-            c.free(a)
 
 
 class TestEngine:
@@ -2389,9 +2298,9 @@ class TestChunkedPrefill:
 
     @pytest.mark.slow
     def test_interleave_schedule_is_deterministic(self):
-        """Identical workload -> identical chunk/dispatch counters; the
-        same fields DECODE_BENCH.json gates exact so the interleave
-        schedule can't silently drift."""
+        """Identical workload -> identical chunk/dispatch counters, so
+        the interleave schedule can't silently drift (the counts
+        themselves: test_serving_counts.py)."""
         e1, out1 = self._run(8)
         e2, out2 = self._run(8)
         keys = ("prefill_calls", "prefill_chunk_dispatches",
@@ -2550,8 +2459,8 @@ class TestShardedServing:
 
     def test_decode_census_matches_hand_formula(self):
         """The comms walker's census of the REAL compiled decode
-        program equals the hand-derived per-layer count — the same
-        contract MULTICHIP_BENCH.json gates exact in CI."""
+        program equals the hand-derived per-layer count, exactly (the
+        same program is a case of test_comms_observability's census)."""
         m = _model()
         eng = self._mesh(m)
         rep = eng.decode_comms_report(horizon=4)   # asserts internally
