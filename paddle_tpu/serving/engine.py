@@ -2027,14 +2027,14 @@ class Engine:
             first, new_k, new_v, new_ks, new_vs, stats = \
                 self._prefill(*call)
             self.pool.rebind(new_k, new_v, new_ks, new_vs)
+        if self.while_in_flight is not None:
+            self.while_in_flight()       # the driver's turn: device busy
         with _span("engine.prefill.wait"):
             first_np = np.asarray(first)     # the one prefill host sync
         if stats is not None:
             self._count_layer_stats(np.asarray(stats)[None], "prefill")
         if self._prefill.misses == miss0:
-            # measured per-token prefill throughput feeding the "auto"
-            # swap-vs-recompute policy (compiling dispatches excluded:
-            # trace+compile seconds are not recompute cost)
+            # feeds the "auto" swap-vs-recompute policy (not when compiling)
             self._prefill_dispatch_s += time.perf_counter() - t0
             self._prefill_tokens_dispatched += int(
                 lengths[:len(entries)].sum())
@@ -2826,20 +2826,20 @@ class Engine:
         self._decode_buckets.add((h, nb, k))
         # KV traffic actually gathered by the fallback scan (and the
         # upper bound for the block-culling Pallas kernel): every lane
-        # reads its nb table-mapped blocks — k + v, all layers — per
-        # step.  bytes_per_block is the pool's ACTUAL footprint: int8
-        # payload + per-token f32 scales when quantized, so the quant
-        # ablation's bandwidth numbers come from this same telemetry.
+        # reads its nb table-mapped blocks, all layers, per step.
+        # bytes_per_block is the pool's ACTUAL footprint (int8 payload
+        # + per-token f32 scales when quantized).
         step_bytes = self.cache.num_slots * nb * self.pool.bytes_per_block
         self._kv_bytes_read += step_bytes * h
         _SRV_KV_BYTES.inc(step_bytes * h, engine=self._profiler_name)
-        # the sampler's batch predicates (``sampling._draw_rows``), from
-        # the host copies of the arrays the program reads them from
+        # the sampler's batch predicates, from the arrays' host copies
         sampling = self._temps > 0
         _SAMPLER_DISPATCH.inc(filters="+".join(
             f for f, asks in (("top_k", self._top_ks > 0),
                               ("top_p", self._top_ps < 1.0))
             if (sampling & asks).any()) or "none")
+        if self.while_in_flight is not None:
+            self.while_in_flight()       # the driver's turn: device busy
         with _span("engine.decode.wait"):
             toks = np.asarray(toks)      # the ONE host sync per horizon
         self._host_syncs += 1
@@ -2857,19 +2857,19 @@ class Engine:
         over every slot.  ``horizon=None`` lets the adaptive policy pick
         the bucket; an explicit value is bucketed to a power of two
         (scanning past a request's retirement is correct — masked — just
-        wasteful).  Returns the requests that finished during this
-        step.
+        wasteful).  Returns the requests that finished during this step.
 
         The span log gets the step by phase: ``engine.admit`` (with the
         ``engine.prefill.build`` / ``.enqueue`` / ``.wait`` / ``.harvest``
         spans of each prefill dispatch inside it), then
         ``engine.decode.prepare`` (twice: block coverage here, the
         uploads in :meth:`_dispatch_horizon`) / ``.enqueue`` / ``.wait``
-        / ``.harvest`` (the walk and the step's counters) and
-        ``engine.step.publish`` (the gauges).  The device
-        certainly has work between an ``enqueue``'s start and its
-        ``wait``'s end; everywhere else the host alone decides whether
-        it does."""
+        / ``.harvest`` and ``engine.step.publish`` (the gauges).  The device
+        certainly has work between an ``enqueue``'s start and its ``wait``'s
+        end; elsewhere the host alone decides.  So between each ``enqueue``
+        and its ``wait`` the driver gets its turn (``while_in_flight``, unset
+        by default): the worker hands the LAST step's tokens to the
+        streaming handlers there, beside the device and not instead of it."""
         t0 = time.time()
         finished = []
         self._update_degradation()
@@ -3513,6 +3513,18 @@ class Engine:
         if self.telemetry is not None:
             s["telemetry_port"] = self.telemetry.port
         return s
+
+    #: The driver's turn while the device works.  Whoever drives the engine
+    #: may set this to a callable of no arguments (``EngineWorker`` sets its
+    #: flush to the streaming handlers); ``_dispatch_horizon`` and
+    #: ``_dispatch_prefill`` call it once a dispatch, after the program's
+    #: ``.enqueue`` span has closed and before its ``.wait`` opens, so host
+    #: work that needs nothing of this dispatch runs beside the device and
+    #: not in its idle time.  ``None`` (the default): no call, nothing
+    #: changes.  It must not touch the engine.  (Down here, not beside
+    #: ``_instances``: lines above the traced functions stay where the
+    #: compile cache's keys have them, ROADMAP debt D9.)
+    while_in_flight = None
 
 
 def _layer_stat_sums(engine):

@@ -7,12 +7,15 @@ Three pieces:
   HTTP layer and a (single-threaded) :class:`~..engine.Engine`.  One
   daemon thread per replica owns every ``submit()/step()/abort()`` on
   its engine; other threads talk to it through a command inbox and get
-  a :class:`StreamHandle` back.  After every ``step()`` the worker
-  flushes each tracked request's newly harvested tokens into its
-  handle's queue — that per-horizon flush is exactly the granularity
-  SSE chunks stream at, and since the engine's sampling is a pure
-  function of ``(seed, token index, logits)``, the streamed token
-  sequence is bitwise what in-process ``Engine.run()`` produces.
+  a :class:`StreamHandle` back.  Once a step the worker flushes each
+  tracked request's newly harvested tokens into its handle's queue —
+  while the NEXT dispatch is in flight, so the handler threads write
+  their frames beside the device and not in its idle time (at once
+  when nothing further will be dispatched).  That per-horizon flush is
+  exactly the granularity SSE chunks stream at, and since the engine's
+  sampling is a pure function of ``(seed, token index, logits)``, the
+  streamed token sequence is bitwise what in-process ``Engine.run()``
+  produces.
 * :class:`PrefixAffinityRouter` — picks a replica per request.  The
   affinity key is the prompt's leading **prefix-cache blocks**, chunked
   exactly the way the radix cache keys its trie
@@ -56,6 +59,7 @@ import threading
 import time
 
 from ...observability import events as _obs_events
+from ...observability import metrics as _obs_metrics
 from ...observability.span import now_ns as _now_ns, span as _span
 from ..faults import (FAULT_STALL, SITE_WORKER_DISPATCH,
                       SITE_WORKER_SUBMIT, _SRV_FAILOVERS, _SRV_RETRIES,
@@ -63,6 +67,11 @@ from ..faults import (FAULT_STALL, SITE_WORKER_DISPATCH,
                       WorkerCrash, WorkerDeadError)
 from ..scheduler import (FINISH_ABORT, FINISH_EOS, FINISH_LENGTH,
                          FINISHED)
+
+_GW_FLUSHES = _obs_metrics.counter(
+    "gateway.flushes",
+    "worker flushes that pushed an event to a stream handle, by whether a "
+    "compiled dispatch was in flight meanwhile (in_flight=true|false)")
 
 
 class TokenChunk(list):
@@ -129,7 +138,8 @@ class EngineWorker:
     All engine mutation happens on that thread: ``submit()``/
     ``abort()``/``drain()`` enqueue commands and block on a reply, the
     loop applies them between horizon dispatches, steps while work
-    exists, and flushes per-request token deltas after every step.
+    exists, and flushes per-request token deltas once a step (see
+    :meth:`_loop_body` for when).
     Reads exposed to other threads (``load``, ``healthy``, ``stats()``)
     are GIL-atomic snapshots of host-side counters.
 
@@ -172,6 +182,8 @@ class EngineWorker:
         self._crash_error = None
         self._dispatch_faults = 0    # transient dispatch errors retried
         self._unstall = threading.Event()  # test valve: release a stall
+        self._handed_over = False    # a dispatch of this step took a flush
+        engine.while_in_flight = self._flush_in_flight
         self._thread = threading.Thread(
             target=self._loop, name=f"gateway.worker:{self.name}",
             daemon=True)
@@ -388,6 +400,10 @@ class EngineWorker:
             _obs_events.instant("serving.worker_crash", cat="serving",
                                 worker=self.name, error=repr(e))
             self._reap_engine()
+        finally:
+            # whoever steps the engine after this thread gets no flush of
+            # a worker that no longer tracks its streams
+            self.engine.while_in_flight = None
 
     def _reap_engine(self):
         """Accounting closure on the way out of a crash: abort every
@@ -418,6 +434,17 @@ class EngineWorker:
             pass
 
     def _loop_body(self):
+        """Inbox, then one ``engine.step()``, for as long as the thread
+        lives.  The step's tokens and finish events are NOT pushed to the
+        handles when it returns: while the engine has more work they wait
+        for the next dispatch's enqueue, a few milliseconds of host work
+        later, and go out between it and its wait
+        (``Engine.while_in_flight``).  The wait then leaves the GIL to the
+        handler threads for as long as the device works, so they build and
+        write their SSE frames beside the device; flushed after the step,
+        they did so while it had nothing queued.  The flush runs at once
+        only where no dispatch will follow soon: the engine has run dry,
+        the step dispatched nothing, or it raised ``DispatchFault``."""
         while True:
             if self._condemned:
                 # condemned mid-flight (e.g. a watchdog false positive
@@ -448,6 +475,7 @@ class EngineWorker:
                             return
                         cmd = None
             if self.engine.scheduler.has_work:
+                self._handed_over = False
                 try:
                     if self._faults is not None:
                         spec = self._faults.fire(SITE_WORKER_DISPATCH,
@@ -460,15 +488,9 @@ class EngineWorker:
                     # transient device error: the same step retries on
                     # the next iteration — requests see one late horizon
                     self._dispatch_faults += 1
-                else:
-                    if self._flush():
-                        # yield the GIL before the next dispatch so
-                        # handler threads woken by the flush get to
-                        # write their SSE frames now, not a
-                        # switch-interval (~5 ms) later; a span of its
-                        # own, so ``worker.flush`` is the flush alone
-                        with _span("worker.yield"):
-                            time.sleep(0)
+                if not (self._handed_over
+                        and self.engine.scheduler.has_work):
+                    self._flush()
             elif self._draining and not self._drained.is_set():
                 self.engine.drain()      # queue empty: releases blocks
                 self._drained.set()
@@ -491,6 +513,7 @@ class EngineWorker:
         self._heartbeat = time.monotonic()
         op, arg, extra, reply = cmd
         if op == "stop":
+            self._flush()        # what is harvested is delivered
             return True
         if op == "submit":
             if self._draining:
@@ -573,13 +596,22 @@ class EngineWorker:
             self._draining = True
         return False
 
-    def _flush(self):
+    def _flush_in_flight(self):
+        """The engine's ``while_in_flight``: a dispatch is enqueued and
+        not yet waited for, so what the last step harvested goes out
+        now."""
+        self._handed_over = True
+        self._flush(in_flight=True)
+
+    def _flush(self, in_flight=False):
         """Push each tracked request's newly harvested tokens (and its
         terminal event) into its handle queue — the per-horizon flush
-        the SSE stream rides.  Returns True if any event was pushed."""
+        the SSE stream rides.  Idempotent: ``handle.sent`` says what a
+        stream has, so a flush that finds nothing new pushes nothing.
+        Returns True if any event was pushed."""
         done, pushed = [], False
         handles = tokens = 0
-        with _span("worker.flush") as sp:
+        with _span("worker.flush", in_flight=in_flight) as sp:
             for rid, h in self._pending.items():
                 n = h.request.n_generated
                 if n > h.sent:
@@ -597,6 +629,8 @@ class EngineWorker:
             for rid in done:
                 del self._pending[rid]
             sp.args.update(handles=handles, tokens=tokens)
+        if pushed:
+            _GW_FLUSHES.inc(in_flight="true" if in_flight else "false")
         return pushed
 
 

@@ -5,21 +5,31 @@ priority-aware admission (bounded starvation), deadline aborts, and
 graceful drain."""
 
 import http.client
+import importlib
 import json
+import queue
 import time
 
 import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models import GPTConfig, GPTForCausalLM
+from paddle_tpu.observability import metrics as obs_metrics
 from paddle_tpu.observability.metrics import validate_exposition
 from paddle_tpu.serving import (
     Engine, EngineConfig, SamplingParams, Scheduler,
 )
-from paddle_tpu.serving.gateway import (
-    EngineWorker, Gateway, GatewayConfig, PrefixAffinityRouter,
-    TenantQuotas, TokenBucket,
+from paddle_tpu.serving.faults import (
+    FAULT_EXCEPTION, FAULT_STALL, SITE_WORKER_DISPATCH, FaultInjector,
+    FaultPlan, FaultSpec, RetryPolicy,
 )
+from paddle_tpu.serving.gateway import (
+    EngineWorker, FleetSupervisor, Gateway, GatewayConfig,
+    PrefixAffinityRouter, TenantQuotas, TokenBucket,
+)
+
+# `observability.span` the attribute is the class; this is the module
+span_log = importlib.import_module("paddle_tpu.observability.span")
 
 TINY = GPTConfig(vocab_size=128, hidden_size=64, intermediate_size=128,
                  num_hidden_layers=2, num_attention_heads=4,
@@ -324,14 +334,187 @@ class TestDrain:
 
 def _drain_handle(h, timeout=30.0):
     """Consume a StreamHandle's event queue to its terminal event."""
+    return _drain_stream(h, timeout)[1:]
+
+
+def _drain_stream(h, timeout=120.0):
+    """``(tokens, "finish", reason)`` of a StreamHandle read to its end."""
     deadline = time.monotonic() + timeout
     toks = []
     while True:
         kind, value = h.events.get(timeout=max(0.1,
                                                deadline - time.monotonic()))
         if kind == "finish":
-            return kind, value
+            return toks, kind, value
         toks.extend(value)
+
+
+# ------------------------------------------------ the worker's hand-over
+#: three requests for two lanes: the third is admitted when the first
+#: retires, while the second still decodes
+HAND_OVER_PROMPTS = ([1, 2, 3, 4, 5], [9, 8, 7, 6, 5, 4, 3], [2, 4, 6, 8])
+HAND_OVER_BUDGETS = (5, 19, 9)
+
+
+def _sampling(kind, i, eos=None):
+    kw = dict(max_new_tokens=HAND_OVER_BUDGETS[i], eos_token_id=eos)
+    if kind == "seeded":
+        kw.update(temperature=0.8, top_k=20, seed=11 + i)
+    return SamplingParams(**kw)
+
+
+def _bare_loop(kind, eos=None):
+    """The same requests through ``Engine.step()`` and no worker."""
+    eng = Engine(_model(), _cfg(num_slots=2), register_profiler=False)
+    assert eng.while_in_flight is None
+    reqs = [eng.submit(list(p), _sampling(kind, i, eos))
+            for i, p in enumerate(HAND_OVER_PROMPTS)]
+    while eng.scheduler.has_work:
+        eng.step()
+    eng.close()
+    return [(list(r.output_ids), r.finish_reason) for r in reqs]
+
+
+def _stalled_at(worker, injector):
+    """Wait until ``injector``'s stall holds ``worker`` before a step."""
+    deadline = time.monotonic() + 120
+    while not injector.fired and time.monotonic() < deadline:
+        time.sleep(0.002)
+    assert injector.fired and injector.fired[0][2] == FAULT_STALL
+
+
+class TestWorkerHandOver:
+    """The worker hands a step's tokens to the handles while the NEXT
+    dispatch is in flight.  Only the moment of the put moved: the tokens,
+    their order, the finish reasons and ``handle.sent`` are the engine's."""
+
+    @pytest.mark.parametrize("kind", ["greedy", "seeded"])
+    def test_streams_equal_a_bare_step_loop(self, kind):
+        # an EOS id one of the streams reaches, so both finish reasons occur
+        free = _bare_loop(kind)
+        eos = free[1][0][7]
+        want = _bare_loop(kind, eos)
+        assert {r for _, r in want} == {"length", "eos"}
+        eng = Engine(_model(), _cfg(num_slots=2), register_profiler=False)
+        w = EngineWorker(eng, "w")
+        try:
+            handles = [w.submit(list(p), sampling=_sampling(kind, i, eos))
+                       for i, p in enumerate(HAND_OVER_PROMPTS)]
+            got = [_drain_stream(h) for h in handles]
+            assert [(t, r) for t, _, r in got] == want
+            assert [h.sent for h in handles] == [len(t) for t, _ in want]
+            w.drain()
+            assert eng.pool.blocks_in_use == 0
+        finally:
+            w.stop()
+            eng.close()
+        assert eng.while_in_flight is None      # the worker took it along
+
+    def test_dispatch_fault_neither_loses_nor_repeats_a_token(self):
+        want = _bare_loop("greedy")
+        eng = Engine(_model(), _cfg(num_slots=2), register_profiler=False)
+        w = EngineWorker(eng, "w")
+        inj = FaultInjector(FaultPlan([
+            FaultSpec(SITE_WORKER_DISPATCH, FAULT_EXCEPTION, at=n)
+            for n in (1, 2, 4, 7)]))
+        w.set_faults(inj)
+        try:
+            handles = [w.submit(list(p), sampling=_sampling("greedy", i))
+                       for i, p in enumerate(HAND_OVER_PROMPTS)]
+            got = [_drain_stream(h) for h in handles]
+            assert [(t, r) for t, _, r in got] == want
+            assert inj.counts() == {FAULT_EXCEPTION: 4}
+            assert w.stats()["worker"]["dispatch_faults"] == 4
+        finally:
+            w.stop()
+            eng.close()
+
+    def test_abort_before_the_deferred_flush_sends_each_token_once(self):
+        """The worker is held before its third step with the second
+        step's tokens harvested and not yet handed over; an abort that
+        lands then flushes them, once, before the terminal event."""
+        want = _bare_loop("greedy")[1][0]
+        eng = Engine(_model(), _cfg(num_slots=2), register_profiler=False)
+        w = EngineWorker(eng, "w")
+        inj = FaultInjector(FaultPlan([
+            FaultSpec(SITE_WORKER_DISPATCH, FAULT_STALL, at=2)]))
+        w.set_faults(inj)
+        try:
+            h = w.submit(list(HAND_OVER_PROMPTS[1]),
+                         sampling=_sampling("greedy", 1))
+            _stalled_at(w, inj)
+            harvested = h.request.n_generated
+            assert 0 < h.sent < harvested       # a step's tokens wait
+            h.abort()
+            w._unstall.set()
+            toks, _, reason = _drain_stream(h)
+            assert reason == "abort"
+            assert h.sent == len(toks) >= harvested
+            assert toks == want[:len(toks)]     # each once, in order
+        finally:
+            w.stop()
+            eng.close()
+
+    def test_failover_before_the_deferred_flush_resumes_from_sent(self):
+        """Condemned while a step's tokens were harvested and not handed
+        over: the adopting replica resumes from ``handle.sent``, so the
+        client sees those tokens once, from the survivor."""
+        prompt, samp = list(HAND_OVER_PROMPTS[1]), _sampling("seeded", 1)
+        ref = Engine(_model(), _cfg(num_slots=2), register_profiler=False)
+        want = list(ref.generate(list(prompt), samp))
+        ref.close()
+        workers = [EngineWorker(Engine(_model(), _cfg(num_slots=2),
+                                       register_profiler=False), f"r{i}")
+                   for i in range(2)]
+        router = PrefixAffinityRouter(workers, retry=RetryPolicy())
+        sup = FleetSupervisor(router, watchdog_timeout_s=None)
+        try:
+            target, _ = router.route(prompt)
+            inj = FaultInjector(FaultPlan([
+                FaultSpec(SITE_WORKER_DISPATCH, FAULT_STALL, at=2)]))
+            target.set_faults(inj)
+            h, w0, _ = router.submit(prompt, sampling=samp)
+            assert w0 is target
+            _stalled_at(target, inj)
+            sent, harvested = h.sent, h.request.n_generated
+            assert 0 < sent < harvested
+            sup.condemn(target, "watchdog_stall")
+            toks, _, reason = _drain_stream(h)
+            assert (toks, reason) == (want, "length")
+            assert h.failovers == 1 and h.worker is not target
+            assert h.request.trace.counts()["resumed_tokens"] == sent
+        finally:
+            sup.stop()
+            for w in workers:
+                if w.alive:
+                    w.stop()
+
+    def test_flush_counter_counts_what_the_spans_say(self):
+        def count(flag):
+            return obs_metrics.value("gateway.flushes", in_flight=flag) or 0
+
+        eng = Engine(_model(), _cfg(num_slots=2), register_profiler=False)
+        w = EngineWorker(eng, "w")
+        mark = span_log.now_ns()
+        before = {f: count(f) for f in ("true", "false")}
+        try:
+            handles = [w.submit(list(p), sampling=_sampling("greedy", i))
+                       for i, p in enumerate(HAND_OVER_PROMPTS)]
+            streams = [_drain_stream(h) for h in handles]
+        finally:
+            w.stop()
+            eng.close()
+        mine = [e for e in span_log.records("worker.flush")
+                if e.start_ns >= mark and e.tid == w._thread.ident]
+        # a flush pushed if it carried tokens or ended a stream; here every
+        # stream's end comes with its last tokens
+        pushed = {flag: sum(1 for e in mine if e.args["tokens"]
+                            and e.args["in_flight"] is (flag == "true"))
+                  for flag in before}
+        assert {f: count(f) - before[f] for f in before} == pushed
+        assert pushed["true"] > pushed["false"] == 1
+        assert sum(e.args["tokens"] for e in mine) == sum(
+            len(t) for t, _, _ in streams)
 
 
 # ----------------------------------------------------------------- HTTP layer

@@ -7,6 +7,7 @@ import http.client
 import importlib
 import json
 import threading
+import time
 
 import jax
 import jax.numpy as jnp
@@ -232,8 +233,7 @@ class TestTrainerSpans:
 ENGINE_SPANS = {
     "worker.inbox": {"commands"},
     "worker.idle": set(),
-    "worker.flush": {"handles", "tokens"},
-    "worker.yield": set(),
+    "worker.flush": {"handles", "tokens", "in_flight"},
     "engine.admit": {"requests"},
     "engine.prefill.build": {"bucket", "lanes"},
     "engine.prefill.enqueue": {"bucket", "lanes", "requests"},
@@ -248,10 +248,29 @@ ENGINE_SPANS = {
 }
 
 
+def _post_stream(port, prompt, max_tokens):
+    conn = http.client.HTTPConnection("127.0.0.1", port, timeout=120)
+    conn.request("POST", "/v1/completions", json.dumps(
+        {"model": "paddle-tpu", "prompt": prompt, "max_tokens": max_tokens,
+         "stream": True}), {"Content-Type": "application/json"})
+    return conn
+
+
+def _read_stream(conn):
+    toks = []
+    for raw in conn.getresponse():
+        line = raw.decode().strip()
+        if line.startswith("data: ") and line != "data: [DONE]":
+            toks += json.loads(line[6:])["choices"][0]["token_ids"]
+    return toks
+
+
 @pytest.fixture(scope="module")
 def served():
-    """Three streamed requests through Gateway -> EngineWorker -> Engine;
-    gives the span records written meanwhile and what was sent."""
+    """Streamed requests through Gateway -> EngineWorker -> Engine: three
+    one after the other, each on an engine that then runs dry, and two that
+    overlap, the second admitted while the first decodes.  Gives the span
+    records written meanwhile and what was sent."""
     paddle.seed(0)
     model = GPTForCausalLM(TINY)
     model.eval()
@@ -261,24 +280,43 @@ def served():
     mark = span_log.now_ns()
     sent = []
     with Gateway([engine], GatewayConfig()) as gw:
-        worker_tid = gw.router.workers[0]._thread.ident
+        worker = gw.router.workers[0]
+        worker_tid = worker._thread.ident
         for i in range(3):
-            conn = http.client.HTTPConnection("127.0.0.1", gw.port,
-                                              timeout=120)
-            conn.request("POST", "/v1/completions", json.dumps(
-                {"model": "paddle-tpu", "prompt": [5 + i, 6, 7, 8, 9],
-                 "max_tokens": 6, "stream": True}),
-                {"Content-Type": "application/json"})
-            toks = []
-            for raw in conn.getresponse():
-                line = raw.decode().strip()
-                if line.startswith("data: ") and line != "data: [DONE]":
-                    toks += json.loads(line[6:])["choices"][0]["token_ids"]
-            sent.append(toks)
+            sent.append(_read_stream(
+                _post_stream(gw.port, [5 + i, 6, 7, 8, 9], 6)))
+        # the overlap, by construction and not by timing: the worker is held
+        # inside the first request's first hand-over (its prefill is in
+        # flight) until the second request lies in its inbox, so the second
+        # is admitted by the very next step, with the first one's tokens of
+        # the step before not yet handed over
+        hand_over, held, go = engine.while_in_flight, threading.Event(), \
+            threading.Event()
+
+        def hold_once():
+            if not held.is_set():
+                held.set()
+                go.wait(60)
+            hand_over()
+
+        engine.while_in_flight = hold_once
+        first = _post_stream(gw.port, [11, 6, 7, 8, 9], 12)
+        assert held.wait(60)
+        second = _post_stream(gw.port, [12, 6, 7, 8, 9], 6)
+        while worker._inbox.empty():
+            time.sleep(0.001)
+        go.set()
+        sent += [_read_stream(first), _read_stream(second)]
     recs = [e for e in span_log.records() if e.start_ns >= mark]
     return {"records": recs, "worker_tid": worker_tid, "sent": sent,
             "builds": [b for b in span_log.builds()
                        if b["start_ns"] >= mark]}
+
+
+def _on_worker(served):
+    return sorted((e for e in served["records"]
+                   if e.tid == served["worker_tid"]),
+                  key=lambda e: e.start_ns)
 
 
 class TestServingSpans:
@@ -329,19 +367,65 @@ class TestServingSpans:
         assert len(uploads) == len(enqueues)
         assert all({"state", "tables"} <= set(e.args) for e in uploads)
 
-    def test_yield_is_its_own_span_after_a_flush_that_pushed(self, served):
-        on_worker = sorted((e for e in served["records"]
-                            if e.tid == served["worker_tid"]
-                            and e.name in ("worker.flush", "worker.yield")),
-                           key=lambda e: e.start_ns)
-        yields = [i for i, e in enumerate(on_worker)
-                  if e.name == "worker.yield"]
-        assert yields
-        for i in yields:
-            flush = on_worker[i - 1]
-            assert flush.name == "worker.flush"
-            assert flush.start_ns + flush.dur_ns <= on_worker[i].start_ns
-            assert on_worker[i].cause is None
+    @pytest.mark.parametrize("kind", ["decode", "prefill"])
+    def test_flush_that_pushed_rides_a_dispatch_in_flight(self, served, kind):
+        """While the engine has more work, a step's tokens go out between
+        the next enqueue's end and its wait's start: both kinds of dispatch
+        carry such a flush, on the worker's thread."""
+        on_worker = _on_worker(served)
+        rode = 0
+        for i, e in enumerate(on_worker):
+            if not (e.name == "worker.flush" and e.args["in_flight"]
+                    and e.args["tokens"]):
+                continue
+            before = [p for p in on_worker[:i] if p.name != "worker.flush"]
+            after = [p for p in on_worker[i + 1:] if p.name != "worker.flush"]
+            enqueue, wait = before[-1], after[0]
+            assert enqueue.name.endswith(".enqueue"), enqueue.name
+            assert wait.name == enqueue.name[:-len("enqueue")] + "wait"
+            assert enqueue.start_ns + enqueue.dur_ns <= e.start_ns
+            assert e.start_ns + e.dur_ns <= wait.start_ns
+            rode += enqueue.name == f"engine.{kind}.enqueue"
+        assert rode
+
+    def test_in_flight_says_where_a_flush_lies(self, served):
+        """``in_flight`` is true exactly for the flushes between an enqueue
+        and its wait; every dispatch carries one."""
+        on_worker = _on_worker(served)
+        inside, carried = False, 0
+        for e in on_worker:
+            if e.name.endswith(".enqueue"):
+                inside = True
+            elif e.name.endswith(".wait"):
+                inside = False
+            elif e.name == "worker.flush":
+                assert e.args["in_flight"] is inside
+                carried += inside
+        assert carried == sum(1 for e in on_worker
+                              if e.name.endswith(".enqueue"))
+
+    def test_no_yield_span_is_written(self, served):
+        assert not [e for e in served["records"] if e.name == "worker.yield"]
+        assert obs_metrics.value("span.seconds", name="worker.yield") is None
+
+    def test_last_tokens_go_out_when_the_engine_runs_dry(self, served):
+        """A flush outside every dispatch that pushed something is the one
+        after the step that left the engine without work: the worker idles
+        next, and each stream's last tokens (and its finish) came that way
+        or with a dispatch, never later."""
+        on_worker = [e for e in _on_worker(served)
+                     if e.name in ("worker.flush", "worker.idle")
+                     or e.name.endswith(".enqueue")]
+        dry = [i for i, e in enumerate(on_worker)
+               if e.name == "worker.flush" and not e.args["in_flight"]
+               and e.args["tokens"]]
+        assert len(dry) == 4            # three alone, then the overlapping two
+        for i in dry:
+            assert on_worker[i + 1].name == "worker.idle"
+        # nothing is handed over at any other moment
+        assert not [e for e in on_worker if e.name == "worker.flush"
+                    and not e.args["in_flight"] and not e.args["tokens"]
+                    and e.args["handles"]]
 
     def test_no_two_queued_stretches_overlap(self, served):
         on_worker = sorted((e for e in served["records"]
@@ -372,7 +456,8 @@ class TestServingSpans:
             if e.name == "gateway.deliver":
                 delivered[e.id] = delivered.get(e.id, 0) + e.args["tokens"]
                 assert e.tid != served["worker_tid"]    # the handler's
-        assert len(delivered) == 3 and None not in delivered
+        assert len(delivered) == len(served["sent"]) == 5
+        assert None not in delivered
         assert sorted(delivered.values()) == sorted(
             len(t) for t in served["sent"])
 
