@@ -7,6 +7,7 @@ from . import bert
 from . import unet
 from . import llama
 from . import deepseek_v2
+from . import xing4
 from .gpt import GPTConfig, GPTModel, GPTForCausalLM, ERNIE_7B, LLAMA2_13B
 from .bert import BertConfig, BertModel, BertForMaskedLM
 from .unet import UNetConfig, UNet2DConditionModel
@@ -15,3 +16,4 @@ from .llama import (
     LLAMA2_7B, LLAMA3_8B,
 )
 from .deepseek_v2 import DeepSeekV2Config, DeepSeekV2Model, DeepSeekV2ForCausalLM
+from .xing4 import Xing4Config, Xing4Model, Xing4ForCausalLM

@@ -1761,12 +1761,12 @@ class Engine:
                         # swap-in consumes nothing before its device
                         # blocks are allocated.
                         break
-            # while draining, the queue can only hold `resumed` requests
-            # (submit() refuses and drain() aborted the rest) — re-admitting
-            # them is finishing in-flight work, so admission proceeds
+            # live lanes never wait on the compile of a wider prefill
+            live = self._prefill_buckets if self.scheduler.running else ()
+            cap = max(live, default=(0,))[0]       # 0: every free lane
             while self.cache.free_slots and self.scheduler.queue_depth:
                 batch = self.scheduler.pop_batch(
-                    self.cache.free_slots,
+                    min(self.cache.free_slots, cap or self.cache.num_slots),
                     bucket_of=self._admission_bucket)
                 if not batch:
                     break
