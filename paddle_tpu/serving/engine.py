@@ -1047,15 +1047,15 @@ class Engine:
             f"unsupported {name} {value!r} (supported: None, 'int8')")
 
     # ------------------------------------------------------------ pure fns
-    def _run_model(self, state_arrays, ids, views):
+    def _run_model(self, state_arrays, ids, views, rows=None):
         """Functionalized forward: raw param arrays + token ids + PagedKV
-        views -> (last-position logits [B, vocab], new views).
-
-        Weight-quantized entries arrive as (int8, f32-scale) pairs and
-        are dequantized HERE, inside the traced program — XLA fuses
-        ``q.astype(f32) * scale`` into the consuming matmul's weight
-        read, so every caller (prefill, horizon scan, verify windows)
-        streams int8 weight bytes without code changes of its own."""
+        views -> (logits, new views): every position's [B, S, vocab] for
+        the horizon scan and verify windows; with ``rows`` [B] (prefill:
+        the one position a lane samples) the LM head projects those rows
+        alone, [B, vocab].  Weight-quantized entries arrive as (int8,
+        f32-scale) pairs dequantized HERE, inside the traced program —
+        XLA fuses the scale into the consuming matmul's weight read, so
+        every caller streams int8 weight bytes with no code of its own."""
         arrays = {}
         for name, a in zip(self._state_names, state_arrays):
             if type(a) is tuple:
@@ -1066,7 +1066,8 @@ class Engine:
         with _tape.no_grad():
             with self.model.use_state(arrays):
                 h, new_views = self.model.model(Tensor(ids), caches=views)
-                logits = self.model._logits(h)
+                logits = self.model._logits(h) if rows is None else \
+                    _head_rows(self.model, h, rows)
         return logits._data, new_views
 
     def _prefill_fn(self, state_arrays, ids, lengths, prefix_lens,
@@ -1094,12 +1095,13 @@ class Engine:
                              ``n_generated - 1`` so the PRNG reproduces
                              the in-flight token bitwise)
 
-        No gathers: cached prefix blocks are ALREADY in the lane's
-        table, so attention reads them in place.  The only data motion
-        is the single-block COW copy; the model then scatters suffix
-        k/v at ``prefix_lens`` (overwriting the COW block from the
-        divergence offset on) and the first token is sampled from the
-        last valid position's logits with ``request_key(seed, count)``.
+        No gathers: cached prefix blocks are ALREADY in the lane's table,
+        so attention reads them in place.  The only data motion is the
+        single-block COW copy; the model then scatters suffix k/v at
+        ``prefix_lens`` (overwriting the COW block from the divergence
+        offset on), the LM head projects each lane's last valid position
+        alone, and the first token is sampled from that row with
+        ``request_key(seed, count)``.
 
         ``pool_ks``/``pool_vs`` are the quantized pool's per-token scale
         buffers (None on the fp path — an empty pytree, so the traced
@@ -1128,10 +1130,8 @@ class Engine:
         views = [PagedKV(pk, pv, tables, prefix_lens, ks, vs)
                  for pk, pv, ks, vs in zip(pool_k, pool_v,
                                            pool_ks, pool_vs)]
-        logits, new_views = self._run_model(state_arrays, ids, views)
-        last = jax.vmap(
-            lambda lg, n: jax.lax.dynamic_index_in_dim(
-                lg, n - 1, axis=0, keepdims=False))(logits, lengths)
+        last, new_views = self._run_model(state_arrays, ids, views,
+                                          rows=lengths - 1)
         if dfa_mask is not None:
             allowed = _unpack_mask(dfa_mask[dfa_state], last.shape[-1])
             last = jnp.where(allowed, last, MASK_FLOOR)
@@ -3535,3 +3535,26 @@ def _layer_stat_sums(engine):
                                    for i in range(n)))
                      for kind in ("prefill", "decode")}
             for c in engine._layer_counters}
+
+
+# (Down here, not beside the other families: lines above the traced
+# functions stay where the compile cache's keys have them, ROADMAP D9.)
+_PREFILL_HEAD = _obs_metrics.counter(
+    "prefill.head.trace", "traces of a prefill program, by the rows its LM "
+    "head projects (one a lane) and the positions it runs (lanes x bucket)")
+
+
+def _lane_rows(x, rows):
+    """``x`` [L, S, ...] -> [L, ...]: position ``rows[i]`` of lane i."""
+    return jax.vmap(lambda a, r: jax.lax.dynamic_index_in_dim(
+        a, r, axis=0, keepdims=False))(x, rows)
+
+
+def _head_rows(model, h, rows):
+    """Prefill's LM head: hidden states ``h`` [L, bucket, hidden] ->
+    logits [L, vocab] of one position a lane (``rows`` [L], the last
+    valid one), the row chosen BEFORE the head, so the other positions
+    are never projected."""
+    lanes, bucket = h.shape[0], h.shape[1]
+    _PREFILL_HEAD.inc(rows=lanes, positions=lanes * bucket)
+    return model._logits(Tensor(_lane_rows(h._data, rows)))

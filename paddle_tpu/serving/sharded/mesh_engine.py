@@ -61,7 +61,7 @@ from ...distributed.shard_map_compat import NO_CHECK, shard_map
 from ...nn import functional as F
 from ...ops.rope import apply_rotary_emb
 from ...tensor import manipulation as M
-from ..engine import Engine
+from ..engine import Engine, _lane_rows
 from ..kv_cache import (PagedKV, model_cache_layout, paged_write,
                         paged_write_quant)
 from ..paged_attention import paged_attention
@@ -299,12 +299,14 @@ class MeshEngine(Engine):
                     tuple(new_ks), tuple(new_vs))
         return logits._data, tuple(new_k), tuple(new_v)
 
-    def _run_model(self, state_arrays, ids, views):
+    def _run_model(self, state_arrays, ids, views, rows=None):
         """The single override point: same contract as the base
         ``_run_model`` (raw param arrays + ids + PagedKV views ->
         (logits, new views)), routed through the mesh forward.  Every
         caller — prefill, the horizon-scan body, spec-decode verify
-        windows — inherits sharding with no code of its own."""
+        windows — inherits sharding with no code of its own.  The mesh
+        forward's sharded head still projects every position; ``rows``
+        (prefill's one position a lane) indexes its logits."""
         num_layers = len(views)
         tables, pos = views[0].tables, views[0].pos
         pool_k = tuple(v.k for v in views)
@@ -322,6 +324,8 @@ class MeshEngine(Engine):
         s = ids.shape[1]
         new_views = [PagedKV(k, v, tables, pos + s, ks, vs)
                      for k, v, ks, vs in zip(nk, nv, nks, nvs)]
+        if rows is not None:
+            logits = _lane_rows(logits, rows)
         return logits, new_views
 
     # ------------------------------------------------------------ census
